@@ -1,7 +1,9 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ must precede jax import: the probes lower against the production mesh.
+# ^ must precede jax import: the probes lower against the production mesh
+# of host devices, and never take the accelerator.
 
 """Roofline analysis — §Roofline of EXPERIMENTS.md.
 
@@ -56,8 +58,7 @@ def measure(cell) -> Terms:
     from repro.analysis import parse_collectives
     lowered = lower_cell(cell)
     compiled = lowered.compile()
-    from repro.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
     return Terms(
         flops=float(cost.get("flops", 0.0)),

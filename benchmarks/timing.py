@@ -58,6 +58,19 @@ def time_rotated(drivers: dict[str, Driver], *, rounds: int = 3,
     return best
 
 
+def timings_device() -> str:
+    """The device a benchmark's timings come from, as JAX reports it;
+    a CPU host is labelled as such, never as a device measurement."""
+    import jax
+
+    d = jax.devices()[0]
+    label = f"{d.platform}: {d.device_kind}"
+    if d.platform == "cpu":
+        label += (" — CPU-host timings (XLA CPU, Pallas kernels in "
+                  "interpret mode), not a TPU measurement")
+    return label
+
+
 def merge_bench_json(path: pathlib.Path, updates: dict) -> dict:
     """Merge top-level keys into a benchmark JSON artifact.
 
@@ -65,10 +78,12 @@ def merge_bench_json(path: pathlib.Path, updates: dict) -> dict:
     long-prompt TTFT, shared-prefix, open-loop load) written by
     different entry points; each writer replaces only its own keys so
     running one benchmark no longer discards the others' records.
+    ``timings_device`` names the device of the latest writer.
     """
     doc: dict = {}
     if path.exists():
         doc = json.loads(path.read_text())
-    doc.update(updates)
+    doc.pop("timings_device", None)
+    doc = {"timings_device": timings_device(), **doc, **updates}
     path.write_text(json.dumps(doc, indent=2) + "\n")
     return doc

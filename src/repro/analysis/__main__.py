@@ -46,6 +46,7 @@ def _repo_root() -> pathlib.Path:
 def _run_tp_subprocess(devices: int) -> dict:
     """Self-spawn the TP half under a forced multi-device CPU."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; the chip is the parent's
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}"
                         ).strip()
@@ -204,4 +205,8 @@ def _kernels_only(args, kernel_report: dict) -> int:
 
 
 if __name__ == "__main__":
+    # a CPU-only tool: compile on the host, never take the accelerator
+    # (one process owns it) — the TP half's child is pinned the same way
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     sys.exit(main())

@@ -37,10 +37,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import LUT_DEQUANT_TAG, LUT_INT_TAG
 
-try:  # jax >= 0.4.36 public location
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
-except Exception:  # pragma: no cover - older pins
-    from jax.core import ClosedJaxpr, Jaxpr, Literal  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 #: primitives that cross the host boundary
 CALLBACK_PRIMITIVES = frozenset({

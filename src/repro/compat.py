@@ -1,79 +1,16 @@
-"""jax version-compatibility shims.
+"""The one mesh factory that pins a jax default.
 
-The repo targets the jax range [0.4.37, 0.7.x).  Two sharding-API
-changes land inside that range:
-
-* ``jax.sharding.AxisType`` (and the ``axis_types=`` keyword on
-  ``jax.make_mesh`` / ``AbstractMesh``) only exists on newer jax; on
-  0.4.x meshes are implicitly Auto-typed.
-* ``AbstractMesh`` changed its constructor from a single
-  ``((name, size), ...)`` tuple (0.4.x) to positional
-  ``(axis_sizes, axis_names, *, axis_types=...)``.
-
-Everything that builds a mesh goes through the two factories below so
-call sites stay version-agnostic.
+``jax.make_mesh`` types new mesh axes ``Explicit``; this repo's meshes
+are ``Auto``-typed (sharding propagated by the compiler), so every
+concrete mesh is built here.
 """
 
 from __future__ import annotations
 
-import enum
-
 import jax
-
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPE = True
-except ImportError:  # jax 0.4.x: meshes are implicitly Auto
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    HAS_AXIS_TYPE = False
-
-
-try:  # jax >= 0.6: top-level export, `check_vma=` kwarg
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _SHARD_MAP_CHECK_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x: experimental, `check_rep=` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KWARG = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """shard_map across the supported jax range (check_vma ≡ check_rep)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KWARG: check_vma})
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on any jax.
-
-    jax 0.4.x returns a one-element list of dicts (one per device
-    program); newer jax returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
-def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Device-free AbstractMesh with Auto axis types on any jax."""
-    from jax.sharding import AbstractMesh
-
-    if HAS_AXIS_TYPE:
-        return AbstractMesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

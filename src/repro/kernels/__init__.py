@@ -6,15 +6,16 @@ attention) plus the exact-softmax baseline:
                    paged kernels (``paged_decode.py`` for single-token
                    decode, ``paged_prefill.py`` for prompt chunks) that
                    serve the continuous-batching engine straight off the
-                   page-major KV pool ``(n_pages, page_size, KVH, Dh)``
+                   head-major KV pool ``(n_pages, KVH, page_size, Dh)``
                    via scalar-prefetched block tables — no contiguous
                    per-slot KV gather on the kernel path, either phase
   flash_attention/ exact online-softmax flash attention
 
 Each package ships <name>.py (pl.pallas_call + BlockSpec), ops.py (the
 jit'd public wrapper with XLA fallback paths) and ref.py (pure-jnp
-oracle).  Kernels are validated in interpret mode on CPU; the multi-pod
-dry-run lowers the XLA paths (Mosaic needs a real TPU backend).
+oracle).  Kernels are validated in interpret mode on CPU, and the paged
+kernels are compiled ahead of time for a TPU v5e at qwen3-32b widths by
+``tests/test_tpu_compile.py``.
 
 Paged-attention dispatch (``ops.lut_attention_paged_decode`` and
 ``ops.lut_attention_paged_prefill`` — ONE matrix covers both; the

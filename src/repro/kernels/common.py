@@ -47,10 +47,12 @@ def dequant_scope():
     """Scope sanctioning an intentional int→float dequant/accumulate."""
     return jax.named_scope(LUT_DEQUANT_TAG)
 
-#: conservative per-core VMEM working-set budget (bytes) used to pick block
-#: shapes; TPU v5e has ~128 MiB VMEM but we budget well under it so double
-#: buffering and spills have room.
-VMEM_BUDGET = 48 * 1024 * 1024
+#: per-kernel VMEM working-set budget (bytes): the scoped VMEM limit the
+#: kernels compile under.  They pass no ``vmem_limit_bytes``, so Mosaic
+#: applies the compiler's default, 16 MiB on TPU v5e — an ahead-of-time
+#: v5e compile refuses a pass needing more ("Scoped allocation with size
+#: 16.01M and limit 16.00M exceeded"), although the core has 128 MiB.
+VMEM_BUDGET = 16 * 1024 * 1024
 
 #: fraction of :data:`VMEM_BUDGET` the static kernel guard keeps free:
 #: ``analysis.kernel_guard`` asserts every kernel pass's derived working
@@ -105,9 +107,9 @@ def kernel_lookup(lut: Array, idx: Array, impl: str) -> Array:
     raise ValueError(f"unknown in-kernel lookup impl {impl!r}")
 
 
-def policy_e_terms(s: Array, m_row: Array, lut_main: Array, method: str,
+def policy_e_terms(s: Array, m_col: Array, lut_main: Array, method: str,
                    exp_step: float, index_mode: str, lookup: str) -> Array:
-    """Per-element numerators given the global row max ``m_row`` (R,),
+    """Per-element numerators given the global row max ``m_col`` (R, 1),
     shared by the paged-decode and paged-prefill pass-2/3 kernels.
 
     ``s`` (R, C) are tail-masked f32 logits;
@@ -118,9 +120,9 @@ def policy_e_terms(s: Array, m_row: Array, lut_main: Array, method: str,
     """
     finite = jnp.isfinite(s)
     if method == "exact":
-        return jnp.where(finite, jnp.exp(s - m_row[:, None]), 0.0)
+        return jnp.where(finite, jnp.exp(s - m_col), 0.0)
     n = lut_main.shape[0]
-    d = m_row[:, None] - s
+    d = m_col - s
     if method == "lut2d":
         from repro.core.lut_softmax import inv_scale
         d = d * inv_scale(exp_step)
@@ -156,12 +158,12 @@ def policy_kernel_tables(method: str, tables):
     raise ValueError(f"unsupported paged-kernel method {method!r}")
 
 
-def rexp_sigma(e_int: Array, s_row: Array, lut_alpha: Array, qmax: int,
+def rexp_sigma(e_int: Array, s_col: Array, lut_alpha: Array, qmax: int,
                index_mode: str, lookup: str) -> Array:
     """Faithful Algorithm 1 per-element σ_int (pre-dequant), shared by the
     blocked-attention and paged-decode pass-3 kernels.
 
-    ``e_int`` (R, C) integer numerators of a tile; ``s_row`` (R,) the
+    ``e_int`` (R, C) integer numerators of a tile; ``s_col`` (R, 1) the
     *global* integer Σ of each row (f32-exact); returns f32 σ_int values
     ``round(e·α/qmax)`` ≤ qmax — callers dequantize by 1/qmax.
     """
@@ -169,21 +171,22 @@ def rexp_sigma(e_int: Array, s_row: Array, lut_alpha: Array, qmax: int,
     inv = inv_scale(qmax)
     n_a = lut_alpha.shape[0]
     rnd = jnp.round if index_mode == "round" else jnp.floor
-    ja = jnp.clip(rnd(s_row * inv).astype(jnp.int32), 0, n_a - 1)
-    alpha = kernel_lookup(lut_alpha, ja, lookup)  # (R,)
+    ja = jnp.clip(rnd(s_col * inv).astype(jnp.int32), 0, n_a - 1)
+    alpha = kernel_lookup(lut_alpha, ja, lookup)  # (R, 1)
     with dequant_scope():  # e·α requantizes by 1/qmax: the sanctioned exit
-        prod = (e_int * alpha[:, None]).astype(jnp.float32)
+        prod = (e_int * alpha).astype(jnp.float32)
     return jnp.round(prod * inv)
 
 
-def lut2d_sigma_int(e_int: Array, s_row: Array, lut_sigma: Array, qmax: int,
+def lut2d_sigma_int(e_int: Array, s_col: Array, lut_sigma: Array, qmax: int,
                     scale_ex: float, scale_sum: float, index_mode: str) -> Array:
     """Algorithm 2 per-element σ_int via the 2-D table, shared by the
     blocked-attention and paged-decode pass-3 kernels.
 
-    Gather-free: the column is selected per row, then the row per
-    element, through unrolled predication (the TPU-native analogue of
-    the paper's MSB wiring).  Returns int32 σ_int ≤ qmax.
+    ``s_col`` (R, 1) is the global Σ of each row.  Gather-free: the
+    column is selected per row, then the row per element, through
+    unrolled predication (the TPU-native analogue of the paper's MSB
+    wiring).  Returns int32 σ_int ≤ qmax.
     """
     from repro.core.lut_softmax import inv_scale
     n_rows, n_cols = lut_sigma.shape
@@ -192,16 +195,16 @@ def lut2d_sigma_int(e_int: Array, s_row: Array, lut_sigma: Array, qmax: int,
         e_f32 = e_int.astype(jnp.float32)
     i_idx = jnp.clip(rnd(e_f32 * inv_scale(qmax * scale_ex))
                      .astype(jnp.int32), 0, n_rows - 1)
-    j_idx = jnp.clip(rnd(s_row * inv_scale(qmax * scale_sum))
-                     .astype(jnp.int32), 1, n_cols) - 1  # (R,)
+    j_idx = jnp.clip(rnd(s_col * inv_scale(qmax * scale_sum))
+                     .astype(jnp.int32), 1, n_cols) - 1  # (R, 1)
     with lut_int_scope():
         sel_col = jnp.zeros((e_int.shape[0], n_rows), dtype=jnp.int32)
         for j in range(n_cols):
-            sel_col = jnp.where(j_idx[:, None] == j, lut_sigma[:, j][None, :],
+            sel_col = jnp.where(j_idx == j, lut_sigma[:, j][None, :],
                                 sel_col)
         sigma_int = jnp.zeros(e_int.shape, dtype=jnp.int32)
         for i in range(n_rows):
-            sigma_int = jnp.where(i_idx == i, sel_col[:, i][:, None],
+            sigma_int = jnp.where(i_idx == i, sel_col[:, i:i + 1],
                                   sigma_int)
         return sigma_int
 

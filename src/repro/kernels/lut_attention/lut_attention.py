@@ -187,8 +187,8 @@ def _rexp_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_re_ref, lut_a_ref,
     e_int = _rexp_e_int(s, m, lut_re_ref[0, :], index_mode, lookup)
 
     # Faithful Algorithm 1: per-element w-bit σ requantization, THEN ·v.
-    sigma_int = rexp_sigma(e_int, s_ref[0, 0], lut_a_ref[0, :], qmax,
-                           index_mode, lookup)
+    sigma_int = rexp_sigma(e_int, s_ref[0, 0][:, None], lut_a_ref[0, :],
+                           qmax, index_mode, lookup)
     v = v_ref[0, 0].astype(jnp.float32)
     o_ref[0, 0] += jax.lax.dot_general(sigma_int, v, (((1,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
@@ -213,8 +213,8 @@ def _lut2d_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_e_ref, lut_s_ref,
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     e_int = _lut2d_e_int(s, m, lut_e_ref[0, :], exp_step, index_mode, lookup)
 
-    sigma_int = lut2d_sigma_int(e_int, s_ref[0, 0], lut_s_ref[...], qmax,
-                                scale_ex, scale_sum, index_mode)
+    sigma_int = lut2d_sigma_int(e_int, s_ref[0, 0][:, None], lut_s_ref[...],
+                                qmax, scale_ex, scale_sum, index_mode)
 
     v = v_ref[0, 0].astype(jnp.float32)
     o_ref[0, 0] += jax.lax.dot_general(

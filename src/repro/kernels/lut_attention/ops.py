@@ -80,6 +80,15 @@ from repro.kernels.lut_attention.paged_prefill import paged_prefill_attention
 Array = jax.Array
 
 
+def _kernel_lookup(policy: SoftmaxPolicy, interpret: bool) -> str:
+    """The in-kernel LUT read.  Mosaic lowers no 1-D gather, so compiled
+    kernels read their tables through select chains; interpret mode keeps
+    the policy's gather.  Both return the same table entries."""
+    if not interpret:
+        return "select"
+    return "gather" if policy.lookup_impl == "gather" else "select"
+
+
 def _tables_for(policy: SoftmaxPolicy):
     if policy.impl == "rexp":
         return lut_builder.build_rexp_tables(policy.precision,
@@ -317,7 +326,7 @@ def lut_attention(
         return lut_attention_pallas(
             q, k, v, tables, method=policy.impl, causal=causal, scale=scale,
             index_mode=policy.index_mode,
-            lookup="gather" if policy.lookup_impl == "gather" else "select",
+            lookup=_kernel_lookup(policy, interpret),
             fused_requant=fused_requant, interpret=interpret)
     if backend == "blocked":
         return lut_attention_blocked(q, k, v, policy, causal=causal,
@@ -485,7 +494,7 @@ def resolve_paged_prefill_backend(backend: str = "auto") -> str:
 
 def lut_attention_paged_prefill(
     q: Array,               # (B, C, H·D)-projected chunk queries (B, H, C, D)
-    k_pages: Array,         # (num_pages, page_size, KVH, D) shared pool
+    k_pages: Array,         # (num_pages, KVH, page_size, D) shared pool
     v_pages: Array,
     block_tables: Array,    # (B, max_pages_per_seq) int32
     q_start: Array,         # (B,) int32 — tokens cached before this chunk
@@ -497,7 +506,7 @@ def lut_attention_paged_prefill(
     q_chunk: int = 512,
     k_chunk: int = 1024,
     mesh=None,
-    k_scales: Array | None = None,  # (num_pages, page_size, KVH) f32 —
+    k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32 —
     v_scales: Array | None = None,  # int8 pool dequant scales (or None)
 ) -> Array:
     """Prefill-chunk attention reading prior keys through the block
@@ -524,7 +533,7 @@ def lut_attention_paged_prefill(
     length: all shapes are fixed by (C, block-table width); only the
     cursors are traced.
     """
-    regime = paged_mesh_regime(mesh, k_pages.shape[2])
+    regime = paged_mesh_regime(mesh, k_pages.shape[1])
     if regime is not None:
         from repro.kernels.lut_attention import sharded_paged
         return sharded_paged.paged_attention_sharded(
@@ -537,7 +546,7 @@ def lut_attention_paged_prefill(
             q, k_pages, v_pages, block_tables, q_start, kv_lens,
             _tables_for(policy), method=policy.impl, scale=scale,
             index_mode=policy.index_mode,
-            lookup="gather" if policy.lookup_impl == "gather" else "select",
+            lookup=_kernel_lookup(policy, resolved == "pallas_interpret"),
             interpret=resolved == "pallas_interpret",
             k_scales=k_scales, v_scales=v_scales)
     if k_scales is not None:
@@ -582,7 +591,7 @@ def _naive_with_bias(q, k, v, policy, causal, scale, k_bias, fused_requant,
 
 
 def gather_pages(pages: Array, block_tables: Array) -> Array:
-    """(P, ps, KVH, Dh) pool + (B, mp) table → (B, KVH, mp·ps, Dh) view.
+    """(P, KVH, ps, Dh) pool + (B, mp) table → (B, KVH, mp·ps, Dh) view.
 
     Logical token order is preserved: page j of a slot covers absolute
     positions [j·ps, (j+1)·ps).  Junk past a slot's length (null-page
@@ -591,31 +600,33 @@ def gather_pages(pages: Array, block_tables: Array) -> Array:
     Pallas kernel streams pages straight from the pool.
     """
     b, mp = block_tables.shape
-    ps, kvh, dh = pages.shape[1], pages.shape[2], pages.shape[3]
-    g = pages[block_tables]                     # (B, mp, ps, KVH, Dh)
-    return g.transpose(0, 3, 1, 2, 4).reshape(b, kvh, mp * ps, dh)
+    kvh, ps, dh = pages.shape[1], pages.shape[2], pages.shape[3]
+    g = pages[block_tables]                     # (B, mp, KVH, ps, Dh)
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, kvh, mp * ps, dh)
 
 
-def gather_page_scales(scales: Array, block_tables: Array) -> Array:
-    """(P, ps, KVH) scale pool + (B, mp) table → (B, KVH, mp·ps) view.
+def gather_page_scales(scales: Array, block_tables: Array,
+                       n_kv_heads: int) -> Array:
+    """(P, 1, KVH·ps) scale pool + (B, mp) table → (B, KVH, mp·ps) view.
 
     Row-aligned with :func:`gather_pages`: scale [b, n, t] dequantizes
     token row t of the gathered int8 K (or V) view.  Dense-path only,
     like the page gather itself.
     """
     b, mp = block_tables.shape
-    ps, kvh = scales.shape[1], scales.shape[2]
-    g = scales[block_tables]                    # (B, mp, ps, KVH)
-    return g.transpose(0, 3, 1, 2).reshape(b, kvh, mp * ps)
+    ps = scales.shape[2] // n_kv_heads
+    g = scales[block_tables].reshape(b, mp, n_kv_heads, ps)
+    return g.transpose(0, 2, 1, 3).reshape(b, n_kv_heads, mp * ps)
 
 
 def _gather_dequant(k_pages, v_pages, block_tables, k_scales, v_scales):
     """Dense-path int8 pool → dequantized (B, KVH, mp·ps, Dh) f32 views."""
     from repro.core.quantization import dequantize_rows
+    kvh = k_pages.shape[1]
     k_seq = dequantize_rows(gather_pages(k_pages, block_tables),
-                            gather_page_scales(k_scales, block_tables))
+                            gather_page_scales(k_scales, block_tables, kvh))
     v_seq = dequantize_rows(gather_pages(v_pages, block_tables),
-                            gather_page_scales(v_scales, block_tables))
+                            gather_page_scales(v_scales, block_tables, kvh))
     return k_seq, v_seq
 
 
@@ -640,7 +651,7 @@ def resolve_paged_backend(backend: str = "auto") -> str:
 
 def lut_attention_paged_decode(
     q: Array,              # (B, H, 1, D) single-token queries
-    k_pages: Array,        # (num_pages, page_size, KVH, D) shared pool
+    k_pages: Array,        # (num_pages, KVH, page_size, D) shared pool
     v_pages: Array,
     block_tables: Array,   # (B, max_pages_per_seq) int32
     kv_lens: Array,        # (B,) int32 — valid keys incl. the new token
@@ -649,7 +660,7 @@ def lut_attention_paged_decode(
     scale: float | None = None,
     backend: str = "auto",  # 'auto' | 'pallas' | 'dense'
     mesh=None,
-    k_scales: Array | None = None,  # (num_pages, page_size, KVH) f32 —
+    k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32 —
     v_scales: Array | None = None,  # int8 pool dequant scales (or None)
 ) -> Array:
     """Decode attention straight off the paged KV pool.
@@ -667,7 +678,7 @@ def lut_attention_paged_decode(
     the pool must carry the matching sharding — see
     ``runtime/partitioning.paged_pool_pspec``).
     """
-    regime = paged_mesh_regime(mesh, k_pages.shape[2])
+    regime = paged_mesh_regime(mesh, k_pages.shape[1])
     if regime is not None:
         from repro.kernels.lut_attention import sharded_paged
         return sharded_paged.paged_attention_sharded(
@@ -678,7 +689,7 @@ def lut_attention_paged_decode(
         return paged_decode_attention(
             q, k_pages, v_pages, block_tables, kv_lens, _tables_for(policy),
             method=policy.impl, scale=scale, index_mode=policy.index_mode,
-            lookup="gather" if policy.lookup_impl == "gather" else "select",
+            lookup=_kernel_lookup(policy, resolved == "pallas_interpret"),
             interpret=resolved == "pallas_interpret",
             k_scales=k_scales, v_scales=v_scales)
     if k_scales is not None:

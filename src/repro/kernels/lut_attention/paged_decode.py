@@ -2,8 +2,8 @@
 
 The continuous-batching decode hot loop.  Every slot in the decode batch
 holds one single-token query and attends to its own sequence, whose K/V
-live scattered across a shared page pool ``(num_pages, page_size, KVH,
-Dh)``.  The dense fallback first *gathers* each slot's pages into a
+live scattered across a shared head-major page pool ``(num_pages, KVH,
+page_size, Dh)``.  The dense fallback first *gathers* each slot's pages into a
 contiguous ``(B, KVH, Lk, D)`` tensor and then materializes full logits
 — exactly the memory traffic the paper's LUT approach exists to avoid.
 This kernel instead streams pages straight out of the pool:
@@ -39,8 +39,8 @@ V-contraction accumulates page-chunked instead of row-at-once).
 
 Total traffic per step: the live pages once per pass plus O(B·G·Dh)
 accumulators — no O(B·mp·ps·D) gather and no (B, H, Lk) logits tensor in
-HBM.  Validated in interpret mode on CPU; Mosaic lowers the same program
-on TPU.
+HBM.  Validated in interpret mode on CPU, and compiled by Mosaic for a
+TPU v5e at qwen3-32b widths (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -66,19 +66,37 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 
 
-def _page_rows(x_ref, sc_ref):
-    """Page block (1, ps, 1, Dh) → (ps, Dh) f32 token rows.
+def _head_scales(sc_ref, page_size):
+    """(ps, 1) f32 dequant scales of this grid cell's KV head.
 
-    ``sc_ref`` is the page's (1, ps, 1) f32 scale block when the pool is
-    int8 (per-token × KV-head symmetric quantization) — the int8→f32
-    upcast then happens here, inside ``dequant_scope`` (the sanctioned
-    exit the jaxpr lint checks for), and nowhere else in the kernel.
+    ``sc_ref`` is the page's ``(1, 1, KVH·ps)`` scale block: every KV
+    head's per-token scales on one head-major lane row
+    (``runtime.paged_cache.scale_pool_shape``).  The cell's ``ps`` lanes
+    are picked and turned into a column by a masked lane sum — exact,
+    since each output sums one scale with zeros.
+    """
+    row = sc_ref[0]                                   # (1, KVH·ps)
+    n = row.shape[-1]
+    first = pl.program_id(1) * page_size
+    lane = jax.lax.broadcasted_iota(jnp.int32, (page_size, n), 1)
+    tok = jax.lax.broadcasted_iota(jnp.int32, (page_size, n), 0)
+    return jnp.sum(jnp.where(lane == first + tok, row, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _page_rows(x_ref, sc_ref):
+    """Page block (1, 1, ps, Dh) → (ps, Dh) f32 token rows.
+
+    ``sc_ref`` is the page's scale block when the pool is int8
+    (per-token × KV-head symmetric quantization) — the int8→f32 upcast
+    then happens here, inside ``dequant_scope`` (the sanctioned exit the
+    jaxpr lint checks for), and nowhere else in the kernel.
     """
     if sc_ref is None:
-        return x_ref[0, :, 0, :].astype(jnp.float32)
+        return x_ref[0, 0].astype(jnp.float32)
+    scales = _head_scales(sc_ref, x_ref.shape[2])
     with dequant_scope():  # int8 page rows × per-token scales
-        return x_ref[0, :, 0, :].astype(jnp.float32) \
-            * sc_ref[0, :, 0][:, None]
+        return x_ref[0, 0].astype(jnp.float32) * scales
 
 
 def _page_logits(q_ref, k_ref, kl_ref, scale, page_size, ks_ref=None):
@@ -104,11 +122,17 @@ def _page_logits(q_ref, k_ref, kl_ref, scale, page_size, ks_ref=None):
 
 
 def _accum_rowmax(s, m_ref):
+    """Fold the (R, ps) logits tile into the (1, 1, R, 1) row-max block.
+
+    Row statistics live in (R, 1) columns throughout (accumulator blocks
+    included) so they broadcast over the tile's lanes without a
+    sublane↔lane relayout — the form Mosaic lowers.
+    """
     @pl.when(pl.program_id(2) == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
-    m_ref[0, 0] = jnp.maximum(m_ref[0, 0], jnp.max(s, axis=-1))
+    m_ref[0, 0] = jnp.maximum(m_ref[0, 0], jnp.max(s, axis=-1, keepdims=True))
 
 
 def _pg_rowmax_kernel(bt_ref, kl_ref, q_ref, k_ref, m_ref, *, scale,
@@ -139,7 +163,7 @@ def _accum_sum(s, m_ref, lut_ref, s_ref, method, exp_step, index_mode,
     e = policy_e_terms(s, m, lut_ref[0, :], method, exp_step, index_mode,
                        lookup)
     with dequant_scope():  # f32-exact integer Σ accumulator
-        s_ref[0, 0] += jnp.sum(e.astype(jnp.float32), axis=-1)
+        s_ref[0, 0] += jnp.sum(e.astype(jnp.float32), axis=-1, keepdims=True)
 
 
 def _pg_sum_kernel(bt_ref, kl_ref, q_ref, k_ref, m_ref, lut_ref, s_ref, *,
@@ -175,10 +199,10 @@ def _accum_weight(s, v, m_ref, s_ref, lut_main_ref, lut_aux_ref, o_ref,
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     e = policy_e_terms(s, m, lut_main_ref[0, :], method, exp_step,
                        index_mode, lookup)
-    s_tot = s_ref[0, 0]  # (G,) global Σ from pass 2
+    s_tot = s_ref[0, 0]  # (R, 1) global Σ from pass 2
 
     if method == "exact":
-        w = e / jnp.maximum(s_tot, jnp.finfo(jnp.float32).tiny)[:, None]
+        w = e / jnp.maximum(s_tot, jnp.finfo(jnp.float32).tiny)
     elif method == "rexp":
         w = rexp_sigma(e, s_tot, lut_aux_ref[0, :], qmax, index_mode,
                        lookup) * inv_scale(qmax)
@@ -219,39 +243,47 @@ def _pg_weight_kernel_int8(bt_ref, kl_ref, q_ref, k_ref, ks_ref, v_ref,
 # ---------------------------------------------------------------------------
 
 
+# Index maps take the grid point and then the scalar-prefetch refs, block
+# table first; ``*_`` absorbs the rest, so the paged-prefill launcher (which
+# prefetches one more array) shares these specs.
+
+
 def _pool_spec(page_size, dh):
-    """One physical page per grid step; the page id comes from the
+    """One physical page of one KV head per grid step — a contiguous
+    (ps, Dh) slab of the head-major pool; the page id comes from the
     scalar-prefetched block table — the paged-pool indirection itself."""
     return pl.BlockSpec(
-        (1, page_size, 1, dh),
-        lambda b, h, p, bt_ref, kl_ref: (bt_ref[b, p], 0, h, 0))
+        (1, 1, page_size, dh),
+        lambda b, h, p, bt_ref, *_: (bt_ref[b, p], h, 0, 0))
 
 
-def _scale_spec(page_size):
-    """The int8 pool's per-page scale block — rides the same
-    scalar-prefetched block-table indirection as its page."""
+def _scale_spec(row):
+    """The int8 pool's per-page scale row (all KV heads, ``row`` =
+    KVH·ps lanes) — rides the same scalar-prefetched block-table
+    indirection as its page; ``_head_scales`` picks the cell's head."""
     return pl.BlockSpec(
-        (1, page_size, 1),
-        lambda b, h, p, bt_ref, kl_ref: (bt_ref[b, p], 0, h))
+        (1, 1, row),
+        lambda b, h, p, bt_ref, *_: (bt_ref[b, p], 0, 0))
 
 
 def _lut_spec(arr):
-    nd = arr.ndim
-    return pl.BlockSpec(arr.shape,
-                        lambda b, h, p, bt_ref, kl_ref, _nd=nd: (0,) * _nd)
+    zeros = (0,) * arr.ndim
+    return pl.BlockSpec(arr.shape, lambda b, h, p, *_: zeros)
+
+
+def _row_specs(rows, dh):
+    """Query, row-statistic and output blocks of one (slot, kv-head)
+    cell holding ``rows`` query rows; resident along the page axis."""
+    q_spec = pl.BlockSpec((1, 1, rows, dh), lambda b, h, p, *_: (b, h, 0, 0))
+    acc_spec = pl.BlockSpec((1, 1, rows, 1), lambda b, h, p, *_: (b, h, 0, 0))
+    return q_spec, acc_spec, q_spec
 
 
 def _grid_specs(g, dh, page_size):
     """The decode dispatch's BlockSpecs — single source for the launcher
     and for ``kernel_spec`` (the static guard's declaration)."""
-    q_spec = pl.BlockSpec((1, 1, g, dh),
-                          lambda bi, hi, p, bt_ref, kl_ref: (bi, hi, 0, 0))
-    kv_spec = _pool_spec(page_size, dh)
-    acc_spec = pl.BlockSpec((1, 1, g),
-                            lambda bi, hi, p, bt_ref, kl_ref: (bi, hi, 0))
-    o_spec = pl.BlockSpec((1, 1, g, dh),
-                          lambda bi, hi, p, bt_ref, kl_ref: (bi, hi, 0, 0))
-    return q_spec, kv_spec, acc_spec, o_spec
+    q_spec, acc_spec, o_spec = _row_specs(g, dh)
+    return q_spec, _pool_spec(page_size, dh), acc_spec, o_spec
 
 
 def _build_kernel_spec(geom, quantized):
@@ -259,6 +291,7 @@ def _build_kernel_spec(geom, quantized):
 
     from repro.analysis.kernel_guard import KernelSpec, Operand, PassSpec
     from repro.core.lut_builder import build_lut2d_tables
+    from repro.runtime.paged_cache import pool_shape, scale_pool_shape
 
     b, h, kvh, dh = geom["b"], geom["h"], geom["kvh"], geom["dh"]
     g = h // kvh
@@ -278,19 +311,22 @@ def _build_kernel_spec(geom, quantized):
 
     page_dtype = "int8" if quantized else "float32"
     q = Operand("q", (b, kvh, g, dh), q_spec)
-    kv = Operand("k_pages", (n_pages, page_size, kvh, dh), kv_spec,
-                 page_dtype, table_indexed=True, index_domain=(0, n_pages))
-    vv = Operand("v_pages", (n_pages, page_size, kvh, dh), kv_spec,
-                 page_dtype, table_indexed=True, index_domain=(0, n_pages))
-    sc = _scale_spec(page_size)
-    ks = Operand("k_scales", (n_pages, page_size, kvh), sc,
+    kv = Operand("k_pages", pool_shape(n_pages, page_size, kvh, dh),
+                 kv_spec, page_dtype, table_indexed=True,
+                 index_domain=(0, n_pages))
+    vv = Operand("v_pages", pool_shape(n_pages, page_size, kvh, dh),
+                 kv_spec, page_dtype, table_indexed=True,
+                 index_domain=(0, n_pages))
+    sc_shape = scale_pool_shape(n_pages, page_size, kvh)
+    sc = _scale_spec(sc_shape[-1])
+    ks = Operand("k_scales", sc_shape, sc,
                  table_indexed=True, index_domain=(0, n_pages))
-    vs = Operand("v_scales", (n_pages, page_size, kvh), sc,
+    vs = Operand("v_scales", sc_shape, sc,
                  table_indexed=True, index_domain=(0, n_pages))
     kk = (kv, ks) if quantized else (kv,)
     vvv = (vv, vs) if quantized else (vv,)
-    m = Operand("m", (b, kvh, g), acc_spec)
-    s = Operand("s_sum", (b, kvh, g), acc_spec)
+    m = Operand("m", (b, kvh, g, 1), acc_spec)
+    s = Operand("s_sum", (b, kvh, g, 1), acc_spec)
     o = Operand("out", (b, kvh, g, dh), o_spec)
     t_main = Operand("lut_main", lut_main.shape, _lut_spec(lut_main), "int32")
     t_aux = Operand("lut_aux", lut_aux.shape, _lut_spec(lut_aux), "int32")
@@ -343,7 +379,7 @@ def kernel_spec_int8(geom):
 
 def paged_decode_attention(
     q: Array,              # (B, H, 1, Dh) single-token queries
-    k_pages: Array,        # (num_pages, page_size, KVH, Dh) shared pool
+    k_pages: Array,        # (num_pages, KVH, page_size, Dh) shared pool
     v_pages: Array,
     block_tables: Array,   # (B, max_pages_per_seq) int32 physical page ids
     kv_lens: Array,        # (B,) int32 — valid keys incl. the new token
@@ -354,7 +390,7 @@ def paged_decode_attention(
     index_mode: str = "round",
     lookup: str = "select",
     interpret: bool | None = None,
-    k_scales: Array | None = None,  # (num_pages, page_size, KVH) f32
+    k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None,
 ) -> Array:
     """Fused paged-decode attention; returns (B, H, 1, Dh) f32.
@@ -374,7 +410,12 @@ def paged_decode_attention(
     Numerics match ``ops.lut_attention_decode_varlen`` on the gathered
     view: identical integer pipeline (bins, e_int, Σ, σ_int); the final
     f32 V-contraction accumulates per page, so outputs agree to f32
-    roundoff (the parity suite pins the tolerance).
+    roundoff on the CPU (the parity suite pins the tolerance).  On a
+    TPU both dots run at Mosaic's default precision, one bf16 pass over
+    f32 operands (as XLA's default matmuls do), so there a logit carries
+    bf16 rounding and a LUT bin may flip against an f32 reference;
+    ``chip_smoke.py`` holds the compiled kernel to the reference on
+    logits that are exact in bf16.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -383,7 +424,7 @@ def paged_decode_attention(
         "int8 pool needs both k_scales and v_scales"
     b, h, lq, dh = q.shape
     assert lq == 1, f"paged decode takes single-token queries, got Lq={lq}"
-    num_pages, page_size, kvh, _ = k_pages.shape
+    num_pages, kvh, page_size, _ = k_pages.shape
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     mp = block_tables.shape[1]
@@ -405,7 +446,7 @@ def paged_decode_attention(
      scale_sum) = policy_kernel_tables(method, tables)
 
     geom = dict(scale=scale, page_size=page_size)
-    sc_spec = _scale_spec(page_size)
+    sc_spec = _scale_spec(kvh * page_size)
     # the int8 variants interleave each page's scale block right after it
     k_in = [kv_spec, sc_spec] if quantized else [kv_spec]
     k_ops = (k_pages, k_scales) if quantized else (k_pages,)
@@ -419,7 +460,7 @@ def paged_decode_attention(
     m = pl.pallas_call(
         functools.partial(rowmax_k, **geom),
         grid_spec=spec([q_spec] + k_in, acc_spec),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         interpret=interpret,
     )(block_tables, kv_lens, qg, *k_ops)
 
@@ -429,7 +470,7 @@ def paged_decode_attention(
                           index_mode=index_mode, lookup=lookup, **geom),
         grid_spec=spec([q_spec] + k_in + [acc_spec, _lut_spec(lut_main)],
                        acc_spec),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         interpret=interpret,
     )(block_tables, kv_lens, qg, *k_ops, m, lut_main)
 

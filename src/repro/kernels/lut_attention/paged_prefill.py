@@ -2,9 +2,10 @@
 
 The chunked-prefill half of the continuous-batching hot path.  Each slot
 carries a ``C``-token prompt *chunk* whose K/V were already scattered
-into the shared page pool ``(num_pages, page_size, KVH, Dh)``; the
-chunk's queries sit at absolute positions ``[q_start, q_start + C)`` and
-attend causally to every key ``< kv_lens`` of their own sequence.  The
+into the shared head-major page pool ``(num_pages, KVH, page_size,
+Dh)``; the chunk's queries sit at absolute positions ``[q_start,
+q_start + C)`` and attend causally to every key ``< kv_lens`` of their
+own sequence.  The
 dense fallback first *gathers* each slot's pages into a contiguous
 ``(B, KVH, Lk, D)`` view — an O(L/C · max_context) read per prompt that
 ``ops.py`` documented as the last densification on the serving path.
@@ -23,9 +24,11 @@ same way ``paged_decode.py`` does:
   inside the chunk are all handled per element (structural padding rows
   ``i ≥ chunk_lens`` compute defined-but-discarded values, identical to
   the oracle's);
-* GQA is handled by grouping: queries arrive as ``(B, KVH, G, C, Dh)``
+* GQA is handled by grouping: queries arrive as ``(B, KVH, G·C, Dh)``
   and each (slot, kv-head) grid cell serves all ``G`` query heads of
-  that KV head from one page read.
+  that KV head, for every chunk row, from one page read — the decode
+  kernel's cell with ``G·C`` rows in place of ``G``, so the two share
+  their accumulators and block specs.
 
 Why multi-pass (same argument as ``paged_decode.py``): the paper's
 Algorithms 1/2 normalize by the *global* row max and the *global* Σe —
@@ -45,8 +48,8 @@ page-chunked instead of row-at-once.
 
 Total traffic per chunk: the live pages once per pass plus O(B·G·C·Dh)
 accumulators — no O(B·mp·ps·D) gather and no (B, H, C, Lk) logits tensor
-in HBM.  Validated in interpret mode on CPU; Mosaic lowers the same
-program on TPU.
+in HBM.  Validated in interpret mode on CPU, and compiled by Mosaic for
+a TPU v5e at qwen3-32b widths (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -59,11 +62,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
-from repro.core.lut_softmax import inv_scale
-from repro.kernels.common import (NEG_INF, dequant_scope, lut2d_sigma_int,
-                                  policy_e_terms, policy_kernel_tables,
-                                  rexp_sigma)
-from repro.kernels.lut_attention.paged_decode import _page_rows
+from repro.kernels.common import NEG_INF, policy_kernel_tables
+from repro.kernels.lut_attention.paged_decode import (_accum_rowmax,
+                                                      _accum_sum,
+                                                      _accum_weight,
+                                                      _lut_spec, _page_rows,
+                                                      _pool_spec, _row_specs,
+                                                      _scale_spec)
 
 Array = jax.Array
 
@@ -73,135 +78,70 @@ Array = jax.Array
 # ---------------------------------------------------------------------------
 
 
-def _chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size,
+def _chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size, chunk,
                   ks_ref=None):
-    """(G, C, ps) f32 logits of this (slot, kv-head, page) cell, masked.
+    """(G·C, ps) f32 logits of this (slot, kv-head, page) cell, masked.
 
-    Key positions are logical: page ``p`` of a slot covers absolute
-    positions [p·ps, (p+1)·ps).  A key at ``pos`` is visible to chunk
-    row ``i`` (absolute query position ``q_start[b] + i``) iff
+    Row ``r = g·C + i`` is query head ``g`` of the group at chunk row
+    ``i``.  Key positions are logical: page ``p`` of a slot covers
+    absolute positions [p·ps, (p+1)·ps).  A key at ``pos`` is visible to
+    chunk row ``i`` (absolute query position ``q_start[b] + i``) iff
     ``pos < kv_lens[b]`` (tail / null-page mask) and
     ``pos ≤ q_start[b] + i`` (causal frontier inside the chunk).
-    ``ks_ref`` is the int8 pool's (1, ps, 1) scale block (see
+    ``ks_ref`` is the int8 pool's scale block (see
     ``paged_decode._page_rows``).
     """
     b = pl.program_id(0)
     p = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)          # (G, C, Dh)
+    q = q_ref[0, 0].astype(jnp.float32)          # (G·C, Dh)
     k = _page_rows(k_ref, ks_ref)                # (ps, Dh)
-    s = jax.lax.dot_general(q, k, (((2,), (1,)), ((), ())),
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    qi = qs_ref[b] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    qi = qs_ref[b] + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), chunk)
     return jnp.where((pos < kl_ref[b]) & (pos <= qi), s, NEG_INF)
 
 
 # ---------------------------------------------------------------------------
-# Pass 1 — global row max (online across pages)
+# The three passes — the decode kernel's accumulators over G·C rows
 # ---------------------------------------------------------------------------
-
-
-def _accum_rowmax(s, m_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-
-    m_ref[0, 0] = jnp.maximum(m_ref[0, 0], jnp.max(s, axis=-1))
 
 
 def _pf_rowmax_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, m_ref, *, scale,
-                      page_size):
+                      page_size, chunk):
     _accum_rowmax(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
-                                page_size), m_ref)
+                                page_size, chunk), m_ref)
 
 
 def _pf_rowmax_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
-                           m_ref, *, scale, page_size):
+                           m_ref, *, scale, page_size, chunk):
     _accum_rowmax(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
-                                page_size, ks_ref=ks_ref), m_ref)
-
-
-# ---------------------------------------------------------------------------
-# Pass 2 — Σ numerators (online across pages)
-# ---------------------------------------------------------------------------
-
-
-def _accum_sum(s, m_ref, lut_ref, s_ref, method, exp_step, index_mode,
-               lookup):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    g, c, ps = s.shape
-    m = m_ref[0, 0]                               # (G, C)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = policy_e_terms(s.reshape(g * c, ps), m.reshape(g * c), lut_ref[0, :],
-                       method, exp_step, index_mode, lookup)
-    with dequant_scope():  # f32-exact integer Σ accumulator
-        s_ref[0, 0] += jnp.sum(e.astype(jnp.float32), axis=-1).reshape(g, c)
+                                page_size, chunk, ks_ref=ks_ref), m_ref)
 
 
 def _pf_sum_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, m_ref, lut_ref,
-                   s_ref, *, scale, page_size, method, exp_step, index_mode,
-                   lookup):
-    _accum_sum(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size),
+                   s_ref, *, scale, page_size, chunk, method, exp_step,
+                   index_mode, lookup):
+    _accum_sum(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size,
+                             chunk),
                m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
 
 
 def _pf_sum_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref, m_ref,
-                        lut_ref, s_ref, *, scale, page_size, method, exp_step,
-                        index_mode, lookup):
+                        lut_ref, s_ref, *, scale, page_size, chunk, method,
+                        exp_step, index_mode, lookup):
     _accum_sum(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size,
-                             ks_ref=ks_ref),
+                             chunk, ks_ref=ks_ref),
                m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
-
-
-# ---------------------------------------------------------------------------
-# Pass 3 — per-element σ · V (faithful requantization, online across pages)
-# ---------------------------------------------------------------------------
-
-
-def _accum_weight(s, v, m_ref, s_ref, lut_main_ref, lut_aux_ref, o_ref,
-                  method, qmax, exp_step, scale_ex, scale_sum, index_mode,
-                  lookup):
-    """Accumulate out += σ(s, m, S) @ V_page with the policy's per-element
-    weights — REXP re-quantizes σ_int per element (Algorithm 1 line 11),
-    2D-LUT reads LUT_σ[i(e), j(S)] (Algorithm 2), exact divides by S.
-    Rows are the flattened (G, C) chunk: the σ helpers are row-generic."""
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    g, c, ps = s.shape
-    m = m_ref[0, 0]
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = policy_e_terms(s.reshape(g * c, ps), m.reshape(g * c),
-                       lut_main_ref[0, :], method, exp_step, index_mode,
-                       lookup)
-    s_tot = s_ref[0, 0].reshape(g * c)  # global Σ from pass 2
-
-    if method == "exact":
-        w = e / jnp.maximum(s_tot, jnp.finfo(jnp.float32).tiny)[:, None]
-    elif method == "rexp":
-        w = rexp_sigma(e, s_tot, lut_aux_ref[0, :], qmax, index_mode,
-                       lookup) * inv_scale(qmax)
-    else:  # lut2d
-        sigma_int = lut2d_sigma_int(e, s_tot, lut_aux_ref[...], qmax,
-                                    scale_ex, scale_sum, index_mode)
-        with dequant_scope():  # σ_int/qmax: the sanctioned exit
-            w = sigma_int.astype(jnp.float32) * inv_scale(qmax)
-
-    o_ref[0, 0] += jax.lax.dot_general(
-        w.astype(jnp.float32), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(g, c, -1)
 
 
 def _pf_weight_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, v_ref, m_ref,
                       s_ref, lut_main_ref, lut_aux_ref, o_ref, *, scale,
-                      page_size, method, qmax, exp_step, scale_ex, scale_sum,
-                      index_mode, lookup):
+                      page_size, chunk, method, qmax, exp_step, scale_ex,
+                      scale_sum, index_mode, lookup):
     _accum_weight(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
-                                page_size),
+                                page_size, chunk),
                   _page_rows(v_ref, None), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
                   scale_sum, index_mode, lookup)
@@ -209,11 +149,11 @@ def _pf_weight_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, v_ref, m_ref,
 
 def _pf_weight_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
                            v_ref, vs_ref, m_ref, s_ref, lut_main_ref,
-                           lut_aux_ref, o_ref, *, scale, page_size, method,
-                           qmax, exp_step, scale_ex, scale_sum, index_mode,
-                           lookup):
+                           lut_aux_ref, o_ref, *, scale, page_size, chunk,
+                           method, qmax, exp_step, scale_ex, scale_sum,
+                           index_mode, lookup):
     _accum_weight(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
-                                page_size, ks_ref=ks_ref),
+                                page_size, chunk, ks_ref=ks_ref),
                   _page_rows(v_ref, vs_ref), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
                   scale_sum, index_mode, lookup)
@@ -224,43 +164,12 @@ def _pf_weight_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
 # ---------------------------------------------------------------------------
 
 
-def _pool_spec(page_size, dh):
-    """One physical page per grid step; the page id comes from the
-    scalar-prefetched block table — the paged-pool indirection itself."""
-    return pl.BlockSpec(
-        (1, page_size, 1, dh),
-        lambda b, h, p, bt_ref, kl_ref, qs_ref: (bt_ref[b, p], 0, h, 0))
-
-
-def _scale_spec(page_size):
-    """The int8 pool's per-page scale block — rides the same
-    scalar-prefetched block-table indirection as its page."""
-    return pl.BlockSpec(
-        (1, page_size, 1),
-        lambda b, h, p, bt_ref, kl_ref, qs_ref: (bt_ref[b, p], 0, h))
-
-
-def _lut_spec(arr):
-    nd = arr.ndim
-    return pl.BlockSpec(
-        arr.shape,
-        lambda b, h, p, bt_ref, kl_ref, qs_ref, _nd=nd: (0,) * _nd)
-
-
 def _grid_specs(g, c, dh, page_size):
     """The prefill dispatch's BlockSpecs — single source for the launcher
-    and for ``kernel_spec`` (the static guard's declaration)."""
-    q_spec = pl.BlockSpec(
-        (1, 1, g, c, dh),
-        lambda bi, hi, p, bt_ref, kl_ref, qs_ref: (bi, hi, 0, 0, 0))
-    kv_spec = _pool_spec(page_size, dh)
-    acc_spec = pl.BlockSpec(
-        (1, 1, g, c),
-        lambda bi, hi, p, bt_ref, kl_ref, qs_ref: (bi, hi, 0, 0))
-    o_spec = pl.BlockSpec(
-        (1, 1, g, c, dh),
-        lambda bi, hi, p, bt_ref, kl_ref, qs_ref: (bi, hi, 0, 0, 0))
-    return q_spec, kv_spec, acc_spec, o_spec
+    and for ``kernel_spec`` (the static guard's declaration).  A cell
+    holds the G·C query rows of its KV head's group."""
+    q_spec, acc_spec, o_spec = _row_specs(g * c, dh)
+    return q_spec, _pool_spec(page_size, dh), acc_spec, o_spec
 
 
 def _build_kernel_spec(geom, quantized):
@@ -268,6 +177,7 @@ def _build_kernel_spec(geom, quantized):
 
     from repro.analysis.kernel_guard import KernelSpec, Operand, PassSpec
     from repro.core.lut_builder import build_lut2d_tables
+    from repro.runtime.paged_cache import pool_shape, scale_pool_shape
 
     b, h, kvh, dh = geom["b"], geom["h"], geom["kvh"], geom["dh"]
     g = h // kvh
@@ -288,21 +198,24 @@ def _build_kernel_spec(geom, quantized):
     lut_aux = l2d.lut_sigma
 
     page_dtype = "int8" if quantized else "float32"
-    q = Operand("q", (b, kvh, g, c, dh), q_spec)
-    kv = Operand("k_pages", (n_pages, page_size, kvh, dh), kv_spec,
-                 page_dtype, table_indexed=True, index_domain=(0, n_pages))
-    vv = Operand("v_pages", (n_pages, page_size, kvh, dh), kv_spec,
-                 page_dtype, table_indexed=True, index_domain=(0, n_pages))
-    sc = _scale_spec(page_size)
-    ks = Operand("k_scales", (n_pages, page_size, kvh), sc,
+    q = Operand("q", (b, kvh, g * c, dh), q_spec)
+    kv = Operand("k_pages", pool_shape(n_pages, page_size, kvh, dh),
+                 kv_spec, page_dtype, table_indexed=True,
+                 index_domain=(0, n_pages))
+    vv = Operand("v_pages", pool_shape(n_pages, page_size, kvh, dh),
+                 kv_spec, page_dtype, table_indexed=True,
+                 index_domain=(0, n_pages))
+    sc_shape = scale_pool_shape(n_pages, page_size, kvh)
+    sc = _scale_spec(sc_shape[-1])
+    ks = Operand("k_scales", sc_shape, sc,
                  table_indexed=True, index_domain=(0, n_pages))
-    vs = Operand("v_scales", (n_pages, page_size, kvh), sc,
+    vs = Operand("v_scales", sc_shape, sc,
                  table_indexed=True, index_domain=(0, n_pages))
     kk = (kv, ks) if quantized else (kv,)
     vvv = (vv, vs) if quantized else (vv,)
-    m = Operand("m", (b, kvh, g, c), acc_spec)
-    s = Operand("s_sum", (b, kvh, g, c), acc_spec)
-    o = Operand("out", (b, kvh, g, c, dh), o_spec)
+    m = Operand("m", (b, kvh, g * c, 1), acc_spec)
+    s = Operand("s_sum", (b, kvh, g * c, 1), acc_spec)
+    o = Operand("out", (b, kvh, g * c, dh), o_spec)
     t_main = Operand("lut_main", lut_main.shape, _lut_spec(lut_main), "int32")
     t_aux = Operand("lut_aux", lut_aux.shape, _lut_spec(lut_aux), "int32")
 
@@ -350,7 +263,7 @@ def kernel_spec_int8(geom):
 
 def paged_prefill_attention(
     q: Array,              # (B, H, C, Dh) chunk queries
-    k_pages: Array,        # (num_pages, page_size, KVH, Dh) shared pool
+    k_pages: Array,        # (num_pages, KVH, page_size, Dh) shared pool
     v_pages: Array,
     block_tables: Array,   # (B, max_pages_per_seq) int32 physical page ids
     q_start: Array,        # (B,) int32 — tokens cached before this chunk
@@ -362,7 +275,7 @@ def paged_prefill_attention(
     index_mode: str = "round",
     lookup: str = "select",
     interpret: bool | None = None,
-    k_scales: Array | None = None,  # (num_pages, page_size, KVH) f32
+    k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None,
 ) -> Array:
     """Fused paged-prefill attention; returns (B, H, C, Dh) f32.
@@ -380,9 +293,14 @@ def paged_prefill_attention(
     Numerics match ``ops.lut_attention_prefill_varlen`` on the gathered
     view: identical integer pipeline (bins, e_int, Σ, σ_int); the final
     f32 V-contraction accumulates per page, so outputs agree to f32
-    roundoff (the parity suite pins the tolerance).  Rows past a chunk's
-    valid length carry the same defined-but-garbage values as the
-    oracle's (the engine discards them).
+    roundoff on the CPU (the parity suite pins the tolerance).  On a
+    TPU both dots run at Mosaic's default precision, one bf16 pass over
+    f32 operands (as XLA's default matmuls do), so there a logit carries
+    bf16 rounding and a LUT bin may flip against an f32 reference;
+    ``chip_smoke.py`` holds the compiled kernel to the reference on
+    logits that are exact in bf16.  Rows past a chunk's valid length
+    carry the same defined-but-garbage values as the oracle's (the
+    engine discards them).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -390,13 +308,13 @@ def paged_prefill_attention(
     assert quantized == (v_scales is not None), \
         "int8 pool needs both k_scales and v_scales"
     b, h, c, dh = q.shape
-    num_pages, page_size, kvh, _ = k_pages.shape
+    num_pages, kvh, page_size, _ = k_pages.shape
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     mp = block_tables.shape[1]
     scale = scale if scale is not None else dh ** -0.5
 
-    qg = q.reshape(b, kvh, g, c, dh)
+    qg = q.reshape(b, kvh, g * c, dh)  # H is KVH-major: rows g·C + i
     block_tables = block_tables.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
     q_start = jnp.asarray(q_start, jnp.int32)
@@ -412,8 +330,8 @@ def paged_prefill_attention(
     (lut_main, lut_aux, exp_step, qmax, scale_ex,
      scale_sum) = policy_kernel_tables(method, tables)
 
-    geom = dict(scale=scale, page_size=page_size)
-    sc_spec = _scale_spec(page_size)
+    geom = dict(scale=scale, page_size=page_size, chunk=c)
+    sc_spec = _scale_spec(kvh * page_size)
     # the int8 variants interleave each page's scale block right after it
     k_in = [kv_spec, sc_spec] if quantized else [kv_spec]
     k_ops = (k_pages, k_scales) if quantized else (k_pages,)
@@ -427,7 +345,7 @@ def paged_prefill_attention(
     m = pl.pallas_call(
         functools.partial(rowmax_k, **geom),
         grid_spec=spec([q_spec] + k_in, acc_spec),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, 1), jnp.float32),
         interpret=interpret,
     )(block_tables, kv_lens, q_start, qg, *k_ops)
 
@@ -437,7 +355,7 @@ def paged_prefill_attention(
                           index_mode=index_mode, lookup=lookup, **geom),
         grid_spec=spec([q_spec] + k_in + [acc_spec, _lut_spec(lut_main)],
                        acc_spec),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, 1), jnp.float32),
         interpret=interpret,
     )(block_tables, kv_lens, q_start, qg, *k_ops, m, lut_main)
 
@@ -450,7 +368,7 @@ def paged_prefill_attention(
         grid_spec=spec([q_spec] + k_in + v_in + [acc_spec, acc_spec,
                         _lut_spec(lut_main), _lut_spec(lut_aux)],
                        o_spec),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, c, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, dh), jnp.float32),
         interpret=interpret,
     )(block_tables, kv_lens, q_start, qg, *k_ops, *v_ops, m, s_sum,
       lut_main, lut_aux)

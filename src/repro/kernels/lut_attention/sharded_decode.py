@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import lut_builder
 from repro.kernels.common import dequant_scope, kernel_lookup

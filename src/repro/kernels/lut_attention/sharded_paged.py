@@ -6,7 +6,7 @@ attend through these dispatchers.  Two regimes, picked by
 ``ops.paged_mesh_regime`` from the GQA KV-head count:
 
 * ``'heads'`` (KVH % tp == 0) — the pool is sharded on the KV-head axis
-  ``P(None, None, 'model', None)``.  Each device runs the *unmodified*
+  ``P(None, 'model', None, None)``.  Each device runs the *unmodified*
   dense block-table reference on its own head group (query heads are
   KVH-major, so a contiguous H/tp slice aligns exactly with a KVH/tp
   slice of the pool): zero collectives inside attention, and the per-head
@@ -45,8 +45,9 @@ Scatter: in the 'pages' regime the K/V token writes must also stay
 slab-local — :func:`scatter_chunk_sharded` clips non-local physical page
 ids out of range and drops them (``mode='drop'``), so each device writes
 only the pages it owns.  In the 'heads' regime the plain
-``pool.at[phys, offs].set`` in ``models/layers.py`` is already local
-(the scattered axes are unsharded).
+``paged_cache.write_tokens`` in ``models/layers.py`` is already local
+(the scattered axes are unsharded; the head-major scale row splits into
+whole per-head lane groups).
 
 Local compute is the dense reference on all backends — a
 Pallas-kernel-inside-shard_map TPU path is future work; the ``backend``
@@ -60,7 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.lut_softmax import inv_scale
 from repro.core.policies import SoftmaxPolicy
 
@@ -123,7 +124,7 @@ def _e_terms(s: Array, m: Array, policy: SoftmaxPolicy, ktabs) -> Array:
     lut_main, _, exp_step, _, _, _ = ktabs
     lk = s.shape[-1]
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = policy_e_terms(s.reshape(-1, lk), m_safe.reshape(-1), lut_main[0],
+    e = policy_e_terms(s.reshape(-1, lk), m_safe.reshape(-1, 1), lut_main[0],
                        policy.impl, exp_step, policy.index_mode, "gather")
     return e.reshape(s.shape)
 
@@ -148,13 +149,13 @@ def _sigma_from_terms(e: Array, s_sum: Array, policy: SoftmaxPolicy,
     _, lut_aux, _, qmax, scale_ex, scale_sum = ktabs
     inv = inv_scale(qmax)
     lk = e.shape[-1]
-    e2, s_row = e.reshape(-1, lk), s_sum.reshape(-1)
+    e2, s_col = e.reshape(-1, lk), s_sum.reshape(-1, 1)
     if policy.impl == "rexp":
-        sigma_int = rexp_sigma(e2, s_row, lut_aux[0], qmax,
+        sigma_int = rexp_sigma(e2, s_col, lut_aux[0], qmax,
                                policy.index_mode, "gather")
     else:  # lut2d
         with dequant_scope():  # σ_int/qmax: the sanctioned exit
-            sigma_int = lut2d_sigma_int(e2, s_row, lut_aux, qmax, scale_ex,
+            sigma_int = lut2d_sigma_int(e2, s_col, lut_aux, qmax, scale_ex,
                                         scale_sum,
                                         policy.index_mode).astype(jnp.float32)
     return sigma_int.reshape(e.shape) * inv
@@ -192,7 +193,7 @@ def _partials_body(policy: SoftmaxPolicy, tables, scale: float, causal: bool,
         else:
             k_view = _ops.gather_pages(k_slab, lbt)    # (B, KVH, mp·ps, D)
             v_view = _ops.gather_pages(v_slab, lbt)
-        lq, ps = q.shape[2], k_slab.shape[1]
+        lq, ps = q.shape[2], k_slab.shape[2]
         lk = k_view.shape[2]
         s = _ref._logits(q, k_view, scale, causal=False)  # (B, H, Lq, Lk)
         pos = jnp.arange(lk)
@@ -217,7 +218,7 @@ def _partials_body(policy: SoftmaxPolicy, tables, scale: float, causal: bool,
 
 def paged_attention_sharded(
     q: Array,               # (B, H, Lq, D); Lq == 1 for decode
-    k_pages: Array,         # (P, ps, KVH, D) — sharded per regime
+    k_pages: Array,         # (P, KVH, ps, D) — sharded per regime
     v_pages: Array,
     block_tables: Array,    # (B, mp) int32
     kv_lens: Array,         # (B,) int32
@@ -228,7 +229,7 @@ def paged_attention_sharded(
     q_start: Array | None = None,  # (B,) int32 — prefill chunks only
     scale: float | None = None,
     axis: str = "model",
-    k_scales: Array | None = None,  # (P, ps, KVH) f32 — int8 pool only
+    k_scales: Array | None = None,  # (P, 1, KVH·ps) f32 — int8 pool only
     v_scales: Array | None = None,
 ) -> Array:
     """Tensor-parallel paged attention for both serving phases.
@@ -258,10 +259,10 @@ def paged_attention_sharded(
     sc_args = (k_scales, v_scales) if quantized else ()
 
     if regime == "heads":
-        if q.shape[1] % tp or k_pages.shape[2] % tp:
+        if q.shape[1] % tp or k_pages.shape[1] % tp:
             raise ValueError(
                 f"'heads' regime needs H ({q.shape[1]}) and KVH "
-                f"({k_pages.shape[2]}) divisible by tp={tp}")
+                f"({k_pages.shape[1]}) divisible by tp={tp}")
 
         def body(q_, k_, v_, bt_, qs_, kl_, *sc_):
             if quantized:
@@ -280,8 +281,8 @@ def paged_attention_sharded(
         out = shard_map(
             body, mesh=mesh,
             in_specs=(P(None, axis, None, None),
-                      P(None, None, axis, None),
-                      P(None, None, axis, None),
+                      P(None, axis, None, None),
+                      P(None, axis, None, None),
                       P(None, None), P(None), P(None)) + sc_specs,
             out_specs=P(None, axis, None, None),
             check_vma=False,
@@ -355,10 +356,10 @@ def kernel_spec(geom):
 
 
 def scatter_chunk_sharded(
-    k_pages: Array, v_pages: Array,   # (P, ps, KVH, D), page-axis sharded
+    k_pages: Array, v_pages: Array,   # (P, KVH, ps, D), page-axis sharded
     phys: Array, offs: Array,         # (B, C) int32 physical page / offset
     k_tok: Array, v_tok: Array,       # (B, C, KVH, D)
-    k_scales: Array | None = None,    # (P, ps, KVH) f32 scale pools,
+    k_scales: Array | None = None,    # (P, 1, KVH·ps) f32 scale pools,
     v_scales: Array | None = None,    # page-axis sharded (int8 pool only)
     k_sc: Array | None = None,        # (B, C, KVH) f32 entering-token scales
     v_sc: Array | None = None,
@@ -384,16 +385,18 @@ def scatter_chunk_sharded(
     slab = k_pages.shape[0] // _tp(mesh, axis)
     quantized = k_scales is not None
 
+    from repro.runtime.paged_cache import write_token_scales, write_tokens
+
     def body(kp, vp, ph, of, kt, vt, *sc):
         lo = jax.lax.axis_index(axis) * slab
         lph = _scatter_page_ids(ph, lo, slab)  # out of range → dropped
-        kp = kp.at[lph, of].set(kt, mode="drop")
-        vp = vp.at[lph, of].set(vt, mode="drop")
+        kp = write_tokens(kp, lph, of, kt, mode="drop")
+        vp = write_tokens(vp, lph, of, vt, mode="drop")
         if not quantized:
             return kp, vp
         ksp, vsp, ks, vs = sc
-        ksp = ksp.at[lph, of].set(ks, mode="drop")
-        vsp = vsp.at[lph, of].set(vs, mode="drop")
+        ksp = write_token_scales(ksp, lph, of, ks, mode="drop")
+        vsp = write_token_scales(vsp, lph, of, vs, mode="drop")
         return kp, vp, ksp, vsp
 
     pool_spec = P(axis, None, None, None)

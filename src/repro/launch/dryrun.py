@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ^ MUST precede every other import: jax locks the device count on first
-# initialization, and the production meshes need 512 placeholder devices.
+# initialization, and the production meshes need 512 placeholder devices —
+# host devices, never the accelerator, which belongs to one process.
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 single-pod 16×16 and multi-pod 2×16×16 meshes; record memory_analysis and
@@ -61,8 +63,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool) -> dict:
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
-        from repro.compat import cost_analysis
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         rec.update(
             status="ok",
             lower_s=round(t_lower, 1),

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.configs import ARCHS, RunConfig, get_arch
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.synthetic import DataConfig, SyntheticDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedules import linear_warmup_cosine
@@ -33,6 +34,7 @@ from repro.runtime.train_loop import (init_train_state, make_eval_step,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
     ap.add_argument("--scale-down", default=None,

@@ -163,23 +163,25 @@ class PagedAttnCache:
     keeps the historical 4-field pytree exactly.
     """
 
-    k_pages: Array       # (n_pages, page_size, KVH, Dh)
+    k_pages: Array       # (n_pages, KVH, page_size, Dh)
     v_pages: Array
     block_tables: Array  # (B, max_pages_per_seq) int32 physical page ids
     lengths: Array       # (B,) int32 — tokens already cached per slot
-    k_scales: Array | None = None   # (n_pages, page_size, KVH) f32
+    k_scales: Array | None = None   # (n_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[1]
+        return self.k_pages.shape[2]
 
     @staticmethod
     def zeros(n_pages: int, page_size: int, kvh: int, dh: int, b: int,
               max_pages_per_seq: int, dtype) -> "PagedAttnCache":
+        from repro.runtime.paged_cache import pool_shape
+        shape = pool_shape(n_pages, page_size, kvh, dh)
         return PagedAttnCache(
-            k_pages=jnp.zeros((n_pages, page_size, kvh, dh), dtype),
-            v_pages=jnp.zeros((n_pages, page_size, kvh, dh), dtype),
+            k_pages=jnp.zeros(shape, dtype),
+            v_pages=jnp.zeros(shape, dtype),
             block_tables=jnp.zeros((b, max_pages_per_seq), jnp.int32),
             lengths=jnp.zeros((b,), jnp.int32),
         )
@@ -204,17 +206,17 @@ class PagedPrefillCache:
     program serves every prompt length: only the two cursors are traced.
     """
 
-    k_pages: Array       # (n_pages, page_size, KVH, Dh)
+    k_pages: Array       # (n_pages, KVH, page_size, Dh)
     v_pages: Array
     block_tables: Array  # (B, max_pages_per_seq) int32
     lengths: Array       # (B,) int32 — tokens cached before this chunk
     chunk_lens: Array    # (B,) int32 — valid tokens entering this chunk
-    k_scales: Array | None = None   # (n_pages, page_size, KVH) f32
+    k_scales: Array | None = None   # (n_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[1]
+        return self.k_pages.shape[2]
 
 
 jax.tree_util.register_dataclass(
@@ -280,6 +282,7 @@ def _paged_prefill_chunk(p: Params, x: Array, cache: PagedPrefillCache, *,
     # padding rows (and anything past the block table) land on the null
     # page, which is garbage by definition — the write needs no branch
     phys = jnp.where(valid & (positions // ps < mp), phys, 0)
+    from repro.runtime.paged_cache import write_token_scales, write_tokens
     quantized = cache.k_scales is not None
     if quantized:
         # int8 pool: quantize at scatter time, one scale per (token, KV
@@ -304,11 +307,11 @@ def _paged_prefill_chunk(p: Params, x: Array, cache: PagedPrefillCache, *,
             k_scales=k_scales, v_scales=v_scales, k_sc=k_sc, v_sc=v_sc,
             mesh=mesh)
     else:
-        k_pages = cache.k_pages.at[phys, offs].set(k_tok)
-        v_pages = cache.v_pages.at[phys, offs].set(v_tok)
+        k_pages = write_tokens(cache.k_pages, phys, offs, k_tok)
+        v_pages = write_tokens(cache.v_pages, phys, offs, v_tok)
         if quantized:
-            k_scales = cache.k_scales.at[phys, offs].set(k_sc)
-            v_scales = cache.v_scales.at[phys, offs].set(v_sc)
+            k_scales = write_token_scales(k_scales, phys, offs, k_sc)
+            v_scales = write_token_scales(v_scales, phys, offs, v_sc)
 
     out = lut_attention_paged_prefill(
         q, k_pages, v_pages, cache.block_tables,
@@ -351,6 +354,7 @@ def _paged_decode(p: Params, x: Array, cache: PagedAttnCache, *,
     offs = cache.lengths % ps
     phys = jnp.take_along_axis(cache.block_tables, page_idx[:, None],
                                axis=1)[:, 0]               # (B,)
+    from repro.runtime.paged_cache import write_token_scales, write_tokens
     quantized = cache.k_scales is not None
     if quantized:
         from repro.core.quantization import quantize_rows
@@ -375,11 +379,11 @@ def _paged_decode(p: Params, x: Array, cache: PagedAttnCache, *,
     else:
         # inactive slots all target the null page; duplicate scatter
         # indices there are harmless (the page is garbage by definition)
-        k_pages = cache.k_pages.at[phys, offs].set(k_tok)
-        v_pages = cache.v_pages.at[phys, offs].set(v_tok)
+        k_pages = write_tokens(cache.k_pages, phys, offs, k_tok)
+        v_pages = write_tokens(cache.v_pages, phys, offs, v_tok)
         if quantized:
-            k_scales = cache.k_scales.at[phys, offs].set(k_sc)
-            v_scales = cache.v_scales.at[phys, offs].set(v_sc)
+            k_scales = write_token_scales(k_scales, phys, offs, k_sc)
+            v_scales = write_token_scales(v_scales, phys, offs, v_sc)
 
     out = lut_attention_paged_decode(q, k_pages, v_pages,
                                      cache.block_tables,

@@ -319,11 +319,10 @@ class ServingEngine:
         self.mesh = mesh
         self.tp = PT.mesh_model_tp(mesh)
         if mesh is not None:
-            shardings = (PT.make_param_shardings(params, mesh, fsdp=False)
-                         if config.shard_params else jax.tree_util.tree_map(
-                             lambda _: PT.replicated_sharding(mesh), params))
-            params = jax.tree_util.tree_map(jax.device_put, params,
-                                            shardings)
+            params = jax.tree_util.tree_map(
+                jax.device_put, params,
+                PT.serving_param_shardings(params, mesh,
+                                           config.shard_params))
         self.model = model
         self.params = params
         self.run_cfg = run

@@ -10,16 +10,18 @@ consumes.
 Layout contract (shared with :class:`repro.models.layers.PagedAttnCache`
 and the Pallas kernel in ``kernels/lut_attention/paged_decode.py``):
 
-* each layer's pool is **page-major** ``(n_pages, page_size, KVH, Dh)``
-  (:func:`pool_shape`) so one block-table entry addresses one contiguous
-  page and the kernel can stream pages straight from HBM — no per-token
-  indirection, no contiguous per-slot gather; with ``kv_dtype='int8'``
-  the pages store int8 and a parallel f32 scale pool
-  ``(n_pages, page_size, KVH)`` (:func:`scale_pool_shape`,
+* each layer's pool is **page-major, then head-major**
+  ``(n_pages, KVH, page_size, Dh)`` (:func:`pool_shape`) so one
+  block-table entry addresses one page and each (page, KV head) is a
+  contiguous ``(page_size, Dh)`` slab the kernels stream straight from
+  HBM — no per-token indirection, no contiguous per-slot gather; with
+  ``kv_dtype='int8'`` the pages store int8 and a parallel f32 scale pool
+  ``(n_pages, 1, KVH·page_size)`` (:func:`scale_pool_shape`,
   :func:`pool_leaf_specs`) shares the page-major leading axis, so every
   page move (COW copy, 'pages'-regime sharding) moves page + scales
   atomically and the device views (:func:`view_arrays` is field-generic)
-  need no new plumbing;
+  need no new plumbing; :func:`write_tokens` / :func:`write_token_scales`
+  are the one scatter into either;
 * physical page 0 is the reserved **null page** — never allocated, the
   target of every unused block-table entry, so inactive slots and
   padding writes land in garbage space by construction;
@@ -53,13 +55,16 @@ def padded_n_pages(n_pages: int, tp: int) -> int:
 
 def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
                head_dim: int, tp: int = 1) -> tuple[int, int, int, int]:
-    """The kernel-facing page-major pool layout, per layer.
+    """The kernel-facing pool layout ``(n_pages, KVH, page_size, Dh)``,
+    per layer.
 
     Single source of truth for the device pool shape: the leading axis
-    is the physical page id (what a block-table entry indexes), so a
-    page's ``(page_size, KVH, Dh)`` tokens are contiguous — the unit the
-    paged-decode kernel DMAs per grid step and the target the chunked
-    prefill scatters each prompt token into through the block table.
+    is the physical page id (what a block-table entry indexes), and
+    within a page each KV head's ``(page_size, Dh)`` tokens are
+    contiguous — the unit the paged kernels DMA per grid step, and a
+    block whose last two dimensions are whole array dimensions, which
+    Mosaic's TPU tiling rule requires (a token-major page would put a
+    size-1 KV-head block inside the tiled dimensions).
 
     ``tp`` > 1 (tensor-parallel serving) rounds the page axis up to a
     multiple of the mesh's 'model' size so it splits into equal device
@@ -67,7 +72,7 @@ def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
     KVH axis instead and needs no padding, but the rounding is harmless
     there, so callers pass the mesh's tp unconditionally.
     """
-    return (padded_n_pages(n_pages, tp), page_size, n_kv_heads, head_dim)
+    return (padded_n_pages(n_pages, tp), n_kv_heads, page_size, head_dim)
 
 
 #: the KV storage dtypes the pool contract admits (``RunConfig.kv_dtype``
@@ -79,17 +84,44 @@ def scale_pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
                      tp: int = 1) -> tuple[int, int, int]:
     """Layout of a quantization-scale pool: one f32 scale per pool row.
 
-    The int8 pool stores each ``(page, token, kv_head)`` row of
-    :func:`pool_shape` as int8 over ``Dh`` with one f32 scale — i.e. the
-    scale pool is the page pool minus its trailing head-dim axis.  Page
-    granularity is what the COW copy and the sharded regimes move
-    atomically (a page's scales live at the same leading index as the
-    page itself); within a page scales are per token × KV head, which
-    keeps the scatter a pure insert — appending a token never requants
-    its neighbours, so engine and lockstep see identical values
+    The int8 pool stores each ``(page, kv_head, token)`` row of
+    :func:`pool_shape` as int8 over ``Dh`` with one f32 scale.  A page's
+    scales sit on one head-major lane row, ``[page, 0, h·page_size + t]``
+    — shape ``(n_pages, 1, KVH·page_size)``.  That is the layout XLA
+    keeps row-major in TPU HBM (128 lanes at qwen3-32b's 8 KV heads ×
+    16 tokens), so the kernels' per-page block ``(1, 1, KVH·page_size)``
+    reads it with no relayout copy; an ``(n_pages, KVH, page_size)`` pool
+    is stored transposed there and would be copied before every kernel
+    call.  Page granularity is what the COW copy and the sharded regimes
+    move atomically (a page's scales live at the same leading index as
+    the page itself); within a page scales are per token × KV head,
+    which keeps the scatter a pure insert — appending a token never
+    requants its neighbours, so engine and lockstep see identical values
     regardless of chunking or physical placement.
     """
-    return (padded_n_pages(n_pages, tp), page_size, n_kv_heads)
+    return (padded_n_pages(n_pages, tp), 1, n_kv_heads * page_size)
+
+
+def write_tokens(pages, phys, offs, tokens, mode=None):
+    """Scatter token rows into a pool of :func:`pool_shape`.
+
+    ``phys`` / ``offs`` (any batch shape ``S``) give each token's
+    physical page and in-page offset; ``tokens`` is ``S + (KVH, Dh)``.
+    ``mode='drop'`` discards writes whose page id is out of range (the
+    'pages'-regime slab-local scatter).
+    """
+    return pages.at[phys, :, offs].set(tokens, mode=mode)
+
+
+def write_token_scales(scales, phys, offs, token_scales, mode=None):
+    """Scatter per-(token, KV head) scales ``S + (KVH,)`` into a pool of
+    :func:`scale_pool_shape` — the scale twin of :func:`write_tokens`."""
+    import jax.numpy as jnp
+
+    kvh = token_scales.shape[-1]
+    page_size = scales.shape[-1] // kvh
+    lanes = jnp.arange(kvh) * page_size + offs[..., None]
+    return scales.at[phys[..., None], 0, lanes].set(token_scales, mode=mode)
 
 
 def pool_leaf_specs(n_pages: int, page_size: int, n_kv_heads: int,

@@ -273,6 +273,16 @@ def replicated_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def serving_param_shardings(params: PyTree, mesh: Mesh,
+                            shard_params: bool) -> PyTree:
+    """Weight placement of the serving engine on a mesh: TP-sharded
+    (:func:`make_param_shardings` without FSDP) or replicated."""
+    if shard_params:
+        return make_param_shardings(params, mesh, fsdp=False)
+    return jax.tree_util.tree_map(lambda _: replicated_sharding(mesh),
+                                  params)
+
+
 def mesh_model_tp(mesh: Mesh | None) -> int:
     """Tensor-parallel degree of a mesh: its 'model' axis size.
 
@@ -288,7 +298,7 @@ def mesh_model_tp(mesh: Mesh | None) -> int:
 
 def paged_pool_pspec(mesh: Mesh | None, n_kv_heads: int,
                      scales: bool = False) -> P:
-    """Spec for one layer's page-major KV pool (n_pages, ps, KVH, Dh).
+    """Spec for one layer's page-major KV pool (n_pages, KVH, ps, Dh).
 
     KV heads take 'model' when divisible (the 'heads' regime of the
     tensor-parallel paged dispatch — attention is fully local per
@@ -299,17 +309,18 @@ def paged_pool_pspec(mesh: Mesh | None, n_kv_heads: int,
     heads-else-length fallback for the contiguous lockstep cache.
 
     ``scales=True`` gives the spec of the int8 pool's f32 scale leaf
-    ``(n_pages, ps, KVH)`` — the page spec minus its trailing Dh axis,
-    so scales always shard exactly with the pages they describe (the
-    'pages' regime keeps page+scale co-resident per slab; the 'heads'
-    regime splits both on KVH).
+    ``(n_pages, 1, KVH·ps)``, so scales always shard exactly with the
+    pages they describe (the 'pages' regime keeps page+scale co-resident
+    per slab; the 'heads' regime splits both on KVH — the scale row is
+    head-major, so an even split of its lanes is an even split of the
+    heads).
     """
     tp = mesh_model_tp(mesh)
     if tp <= 1:
         return P()
     if n_kv_heads % tp == 0:
         return P(None, None, "model") if scales \
-            else P(None, None, "model", None)
+            else P(None, "model", None, None)
     return P("model", None, None) if scales else P("model", None, None, None)
 
 

@@ -73,7 +73,7 @@ def gpipe_forward(
             axis)
         return outs
 
-    from repro.compat import shard_map
+    from jax import shard_map
     specs_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
     return shard_map(
         shard_body, mesh=mesh,

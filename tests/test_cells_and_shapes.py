@@ -3,7 +3,7 @@
 import jax
 import numpy as np
 import pytest
-from repro.compat import make_abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.configs import ARCHS, SHAPES, get_arch, shape_applicable
 from repro.configs.base import RunConfig
 from repro.core.policies import EXACT
@@ -105,6 +105,6 @@ def test_decode_state_struct_abstract():
 def test_mesh_factories():
     from repro.launch.mesh import make_production_mesh
     # AbstractMesh mirrors the factory shapes without touching devices
-    m1 = make_abstract_mesh((16, 16), ("data", "model"))
-    m2 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    m1 = AbstractMesh((16, 16), ("data", "model"))
+    m2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert m1.size == 256 and m2.size == 512

@@ -140,13 +140,11 @@ def test_paged_prefill_reads_prior_keys_through_block_tables(rng):
     kv_len, c = 11, 5                      # 6 prior + 5 chunk keys
     q, k_log, v_log = _qkv(rng_, 1, 4, kvh, c, mp * ps, d)
     pages = [3, 1, 4, 2]                   # scrambled physical placement
-    pool_k = np.zeros((6, ps, kvh, d), np.float32)
-    pool_v = np.zeros((6, ps, kvh, d), np.float32)
+    pool_k = np.zeros((6, kvh, ps, d), np.float32)
+    pool_v = np.zeros((6, kvh, ps, d), np.float32)
     for j, pg in enumerate(pages):
-        pool_k[pg] = np.asarray(k_log)[0, :, j * ps:(j + 1) * ps].transpose(
-            1, 0, 2)
-        pool_v[pg] = np.asarray(v_log)[0, :, j * ps:(j + 1) * ps].transpose(
-            1, 0, 2)
+        pool_k[pg] = np.asarray(k_log)[0, :, j * ps:(j + 1) * ps]
+        pool_v[pg] = np.asarray(v_log)[0, :, j * ps:(j + 1) * ps]
     out = lut_attention_paged_prefill(
         q, jnp.asarray(pool_k), jnp.asarray(pool_v),
         jnp.asarray([pages], jnp.int32),

@@ -77,11 +77,11 @@ def run_py(code: str, devices: int = 4, timeout: int = 560) -> str:
 
 def test_mesh_regime_resolver():
     """The mesh rows of the dispatch matrix, on abstract meshes."""
-    from repro.compat import make_abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.kernels.lut_attention.ops import paged_mesh_regime
-    tp4 = make_abstract_mesh((1, 4), ("data", "model"))
-    tp1 = make_abstract_mesh((1, 1), ("data", "model"))
-    no_model = make_abstract_mesh((4,), ("data",))
+    tp4 = AbstractMesh((1, 4), ("data", "model"))
+    tp1 = AbstractMesh((1, 1), ("data", "model"))
+    no_model = AbstractMesh((4,), ("data",))
     assert paged_mesh_regime(None, 4) is None
     assert paged_mesh_regime(tp1, 4) is None          # tp == 1: single-device
     assert paged_mesh_regime(no_model, 4) is None
@@ -95,19 +95,19 @@ def test_pool_pspec_heads_else_pages():
     """paged_pool_pspec mirrors cache_pspec's heads-else-length fallback:
     KV heads over 'model' when divisible, else the page axis."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.runtime.partitioning import paged_pool_pspec
-    tp4 = make_abstract_mesh((1, 4), ("data", "model"))
+    tp4 = AbstractMesh((1, 4), ("data", "model"))
     assert paged_pool_pspec(None, 4) == P()
-    assert paged_pool_pspec(tp4, 4) == P(None, None, "model", None)
+    assert paged_pool_pspec(tp4, 4) == P(None, "model", None, None)
     assert paged_pool_pspec(tp4, 3) == P("model", None, None, None)
 
 
 def test_pool_shape_pads_page_axis_to_slabs():
     from repro.runtime.paged_cache import padded_n_pages, pool_shape
     assert padded_n_pages(30, 4) == 32 and padded_n_pages(32, 4) == 32
-    assert pool_shape(30, 8, 2, 16, tp=4) == (32, 8, 2, 16)
-    assert pool_shape(30, 8, 2, 16) == (30, 8, 2, 16)
+    assert pool_shape(30, 8, 2, 16, tp=4) == (32, 2, 8, 16)
+    assert pool_shape(30, 8, 2, 16) == (30, 2, 8, 16)
     with pytest.raises(ValueError):
         padded_n_pages(8, 0)
 
@@ -472,8 +472,8 @@ def problem(rng, b, kvh, g, kv_lens, ps=4, mp=5, dh=16):
     h = kvh * g
     n_pages = -(-(1 + b * mp) // 4) * 4   # slab-divisible (tp=4)
     q = jnp.asarray(rng.normal(size=(b, h, 1, dh)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
     bt = np.zeros((b, mp), np.int32)
     for i, kl in enumerate(kv_lens):
         n_owned = -(-int(kl) // ps)

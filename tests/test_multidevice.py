@@ -182,14 +182,14 @@ def test_dryrun_machinery_small_mesh(arch, shape):
     launch/dryrun.py; this keeps the machinery under CI.)"""
     run_py(rf"""
 import jax
-from repro.compat import cost_analysis, make_mesh
+from repro.compat import make_mesh
 from repro.launch.cells import build_cell, lower_cell
 from repro.analysis import parse_collectives
 
 mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
 cell = build_cell('{arch}', '{shape}', mesh)
 compiled = lower_cell(cell).compile()
-cost = cost_analysis(compiled)
+cost = compiled.cost_analysis()
 assert cost['flops'] > 0
 coll = parse_collectives(compiled.as_text())
 assert coll['total'].count >= 0

@@ -81,7 +81,7 @@ def test_block_table_row_pads_with_null():
 
 def test_gather_pages_reassembles_logical_order(rng):
     n_pages, ps, kvh, dh = 9, 4, 2, 8
-    pool = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh))
+    pool = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh))
                        .astype(np.float32))
     # two slots with interleaved, out-of-order physical pages
     bt = jnp.asarray(np.array([[5, 2, 8], [1, 7, NULL_PAGE]], np.int32))
@@ -91,7 +91,7 @@ def test_gather_pages_reassembles_logical_order(rng):
     for b in range(2):
         for j, pg in enumerate(np.asarray(bt)[b]):
             got = np.asarray(out)[b, :, j * ps:(j + 1) * ps]
-            want = np_pool[pg].transpose(1, 0, 2)  # (ps,KVH,dh)→(KVH,ps,dh)
+            want = np_pool[pg]                     # (KVH, ps, dh)
             np.testing.assert_array_equal(got, want)
 
 
@@ -131,7 +131,8 @@ def test_paged_decode_matches_contiguous_decode(rng):
             _, cache = L.apply_attention(
                 p, jnp.asarray(hist[i:i + 1, :prompt_lens[i]]), n_heads=h,
                 n_kv_heads=kvh, head_dim=dh, policy=impl, cache=cache)
-            chunk = lambda a: a[0].transpose(1, 0, 2).reshape(mp, ps, kvh, dh)
+            chunk = lambda a: a[0].reshape(kvh, mp, ps, dh).transpose(
+                1, 0, 2, 3)
             k_pages = k_pages.at[jnp.asarray(pages)].set(chunk(cache.k))
             v_pages = v_pages.at[jnp.asarray(pages)].set(chunk(cache.v))
         paged = L.PagedAttnCache(k_pages=k_pages, v_pages=v_pages,

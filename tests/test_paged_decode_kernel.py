@@ -35,9 +35,9 @@ def _paged_problem(rng, *, b=3, kvh=2, g=2, dh=16, ps=4, mp=5,
     n_pages = 1 + b * mp  # null page + every slot fully allocated
     q = jnp.asarray(rng.normal(size=(b, h, 1, dh)).astype(np.float32))
     k_pages = jnp.asarray(
-        rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
+        rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
     v_pages = jnp.asarray(
-        rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
+        rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
     phys = np.arange(1, n_pages)
     if shuffle:
         phys = rng.permutation(phys)
@@ -95,7 +95,7 @@ def test_kernel_ignores_junk_pages(rng):
     owned = set()
     bt_np = np.asarray(bt)
     for i, kl in enumerate(np.asarray(kls)):
-        owned.update(bt_np[i, :-(-int(kl) // kp.shape[1])])
+        owned.update(bt_np[i, :-(-int(kl) // kp.shape[2])])
     junk = [p for p in range(kp.shape[0]) if p not in owned]
     kp2 = kp.at[jnp.asarray(junk)].set(1e6)
     vp2 = vp.at[jnp.asarray(junk)].set(-1e6)

@@ -51,9 +51,9 @@ def _paged_problem(rng, *, b=3, kvh=2, g=2, dh=16, ps=4, mp=5, c=6,
     n_pages = 1 + b * mp  # null page + every slot fully allocated
     q = jnp.asarray(rng.normal(size=(b, h, c, dh)).astype(np.float32))
     k_pages = jnp.asarray(
-        rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
+        rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
     v_pages = jnp.asarray(
-        rng.normal(size=(n_pages, ps, kvh, dh)).astype(np.float32))
+        rng.normal(size=(n_pages, kvh, ps, dh)).astype(np.float32))
     phys = np.arange(1, n_pages)
     if shuffle:
         phys = rng.permutation(phys)
@@ -126,11 +126,11 @@ def test_chunk_walk_reassembles_whole_prompt(rng, impl):
     k_log = gen((1, kvh, mp * ps, dh))
     v_log = gen((1, kvh, mp * ps, dh))
     pages = list(1 + rng_.permutation(mp))       # scrambled placement
-    pool_k = np.zeros((1 + mp, ps, kvh, dh), np.float32)
-    pool_v = np.zeros((1 + mp, ps, kvh, dh), np.float32)
+    pool_k = np.zeros((1 + mp, kvh, ps, dh), np.float32)
+    pool_v = np.zeros((1 + mp, kvh, ps, dh), np.float32)
     for j, pg in enumerate(pages):
-        pool_k[pg] = k_log[0, :, j * ps:(j + 1) * ps].transpose(1, 0, 2)
-        pool_v[pg] = v_log[0, :, j * ps:(j + 1) * ps].transpose(1, 0, 2)
+        pool_k[pg] = k_log[0, :, j * ps:(j + 1) * ps]
+        pool_v[pg] = v_log[0, :, j * ps:(j + 1) * ps]
     bt = jnp.asarray([pages], jnp.int32)
     whole = lut_attention(q_all, jnp.asarray(k_log), jnp.asarray(v_log),
                           pol, causal=True, backend="naive",
@@ -162,7 +162,7 @@ def test_kernel_ignores_junk_pages(rng):
     owned = set()
     bt_np = np.asarray(bt)
     for i, kl in enumerate(np.asarray(kls)):
-        owned.update(bt_np[i, :-(-int(kl) // kp.shape[1])])
+        owned.update(bt_np[i, :-(-int(kl) // kp.shape[2])])
     junk = [p for p in range(kp.shape[0]) if p not in owned]
     kp2 = kp.at[jnp.asarray(junk)].set(1e6)
     vp2 = vp.at[jnp.asarray(junk)].set(-1e6)
@@ -286,9 +286,9 @@ def test_dispatch_matrix_docs_match_resolvers():
     # ... and the two mesh rows: heads when the GQA KV-head count
     # divides the axis, pages otherwise (the full resolver unit test —
     # no-mesh, tp=1, missing axis — lives in test_engine_tp.py)
-    from repro.compat import make_abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.kernels.lut_attention.ops import paged_mesh_regime
-    tp4 = make_abstract_mesh((1, 4), ("data", "model"))
+    tp4 = AbstractMesh((1, 4), ("data", "model"))
     assert paged_mesh_regime(tp4, 4) == "heads"
     assert paged_mesh_regime(tp4, 3) == "pages"
 

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.configs import ARCHS, RunConfig
 from repro.models import build_model
 from repro.runtime import partitioning as PT
@@ -15,7 +15,7 @@ from repro.runtime import partitioning as PT
 
 def _mesh_abstract(shape=(2, 16, 16), axes=("pod", "data", "model")):
     # AbstractMesh builds specs without devices
-    return make_abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 MESH = _mesh_abstract()
