@@ -29,9 +29,11 @@ class SoftmaxPolicy:
       alpha_len: LUT_α length (x_s + 1).  None → paper Table 8 default.
       index_mode: ``round`` (centered bins; default) or ``floor``
         (truncating MSB extraction, the literal HW reading).
-      lookup_impl: kernel-level realization of the table read —
-        ``gather`` (dynamic gather) or ``onehot`` (one-hot × LUT matmul on
-        the MXU; the TPU-native adaptation, see DESIGN.md §2).
+      lookup_impl: realization of the table read in the XLA softmax and
+        the LUT-softmax kernels — ``gather`` (dynamic gather) or
+        ``onehot`` (one-hot × LUT matmul on the MXU; the TPU-native
+        adaptation, see DESIGN.md §2).  The attention kernels always
+        read through select chains, the one form Mosaic lowers.
       use_kernel: route through the fused Pallas kernels where available.
       max_norm: apply max-subtraction before approximating.  Always True
         for the paper's methods; exposed so prior-art Eq. (11) (no norm)

@@ -10,8 +10,9 @@ arbitrary gather.  Three lowerings, chosen per table size:
   α/σ tables this is essentially free and needs no gather support at all
   (this is the piecewise-constant LUT re-expressed as predication — the
   TPU-native analogue of the paper's MSB wiring).
-* ``gather`` — ``jnp.take``; exercised in interpret mode and on backends
-  with dynamic-gather support.
+* ``gather`` — ``jnp.take``; for XLA paths and interpret mode only:
+  Mosaic lowers no 1-D gather, so the LUT-attention kernels always
+  read through ``select``.
 * one-hot × LUT on the MXU is numerically identical to ``select`` and is
   what ``select`` amortizes into when XLA vectorizes the chain; see
   DESIGN.md §2 for the napkin math.
@@ -61,6 +62,15 @@ VMEM_BUDGET = 16 * 1024 * 1024
 #: geometry, leaving room for Mosaic spills and semaphore state.
 VMEM_GUARD_HEADROOM = 0.25
 
+#: precision of every attention product (the QK logits and σ·V) in the
+#: LUT-attention kernels and their dense references.  At the default
+#: precision a TPU rounds f32 operands to one bf16 pass, and the LUT
+#: policies' discrete bins turn that rounding into different table
+#: entries (one bin is a whole step of the softmax numerator).  HIGHEST
+#: keeps the f32 semantics the CPU suites check — on the CPU it changes
+#: nothing, on a TPU the MXU runs the f32 contraction in several passes.
+ATTN_PRECISION = jax.lax.Precision.HIGHEST
+
 MXU_ALIGN = 128  # MXU systolic dims; block shapes are multiples of this
 SUBLANE = 8
 
@@ -108,7 +118,8 @@ def kernel_lookup(lut: Array, idx: Array, impl: str) -> Array:
 
 
 def policy_e_terms(s: Array, m_col: Array, lut_main: Array, method: str,
-                   exp_step: float, index_mode: str, lookup: str) -> Array:
+                   exp_step: float, index_mode: str,
+                   lookup: str = "select") -> Array:
     """Per-element numerators given the global row max ``m_col`` (R, 1),
     shared by the paged-decode and paged-prefill pass-2/3 kernels.
 
@@ -159,7 +170,7 @@ def policy_kernel_tables(method: str, tables):
 
 
 def rexp_sigma(e_int: Array, s_col: Array, lut_alpha: Array, qmax: int,
-               index_mode: str, lookup: str) -> Array:
+               index_mode: str, lookup: str = "select") -> Array:
     """Faithful Algorithm 1 per-element σ_int (pre-dequant), shared by the
     blocked-attention and paged-decode pass-3 kernels.
 

@@ -36,8 +36,9 @@ from jax.experimental import pallas as pl
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
 from repro.core.lut_softmax import inv_scale
-from repro.kernels.common import (kernel_lookup, lut2d_sigma_int, pad_axis_to,
-                                  rexp_sigma, round_up)
+from repro.kernels.common import (ATTN_PRECISION, lut2d_sigma_int,
+                                  pad_axis_to, rexp_sigma, round_up,
+                                  select_lookup)
 
 Array = jax.Array
 
@@ -54,6 +55,7 @@ def _block_logits(q_ref, k_ref, scale, causal, lq, lk, lk_valid, bq, bk):
     q = q_ref[0, 0].astype(jnp.float32)  # (BQ, D)
     k = k_ref[0, 0].astype(jnp.float32)  # (BK, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=ATTN_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     qb = pl.program_id(2)
     kb = pl.program_id(3)
@@ -66,7 +68,7 @@ def _block_logits(q_ref, k_ref, scale, causal, lq, lk, lk_valid, bq, bk):
     return jnp.where(mask, s, NEG_INF)
 
 
-def _rexp_e_int(s, m, lut_re, index_mode, lookup):
+def _rexp_e_int(s, m, lut_re, index_mode):
     """REXP numerators for a logits tile given the (global) row max.
 
     Masked (-inf) logits — causal or KV padding — yield hard zeros, never
@@ -77,10 +79,10 @@ def _rexp_e_int(s, m, lut_re, index_mode, lookup):
     d = jnp.where(finite, m[:, None] - s, float(n - 1))
     rnd = jnp.round if index_mode == "round" else jnp.floor
     idx = jnp.clip(rnd(d).astype(jnp.int32), 0, n - 1)
-    return jnp.where(finite, kernel_lookup(lut_re, idx, lookup), 0)
+    return jnp.where(finite, select_lookup(lut_re, idx), 0)
 
 
-def _lut2d_e_int(s, m, lut_e, exp_step, index_mode, lookup):
+def _lut2d_e_int(s, m, lut_e, exp_step, index_mode):
     """2D-LUT numerators for a logits tile given the (global) row max."""
     n = lut_e.shape[0]
     finite = jnp.isfinite(s)
@@ -88,7 +90,7 @@ def _lut2d_e_int(s, m, lut_e, exp_step, index_mode, lookup):
                   float(n - 1))
     rnd = jnp.round if index_mode == "round" else jnp.floor
     idx = jnp.clip(rnd(d).astype(jnp.int32), 0, n - 1)
-    return jnp.where(finite, kernel_lookup(lut_e, idx, lookup), 0)
+    return jnp.where(finite, select_lookup(lut_e, idx), 0)
 
 
 # ---------------------------------------------------------------------------
@@ -114,8 +116,7 @@ def _rowmax_kernel(q_ref, k_ref, m_ref, *, scale, causal, lq, lk, lk_valid,
 
 
 def _sum_kernel(q_ref, k_ref, m_ref, lut_ref, s_ref, *, scale, causal,
-                lq, lk, lk_valid, bq, bk, method, exp_step, index_mode,
-                lookup):
+                lq, lk, lk_valid, bq, bk, method, exp_step, index_mode):
     kb = pl.program_id(3)
 
     @pl.when(kb == 0)
@@ -127,15 +128,15 @@ def _sum_kernel(q_ref, k_ref, m_ref, lut_ref, s_ref, *, scale, causal,
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     lut = lut_ref[0, :]
     if method == "rexp":
-        e_int = _rexp_e_int(s, m, lut, index_mode, lookup)
+        e_int = _rexp_e_int(s, m, lut, index_mode)
     else:
-        e_int = _lut2d_e_int(s, m, lut, exp_step, index_mode, lookup)
+        e_int = _lut2d_e_int(s, m, lut, exp_step, index_mode)
     s_ref[0, 0] += jnp.sum(e_int.astype(jnp.float32), axis=-1)
 
 
 def _fused_sum_av_kernel(q_ref, k_ref, v_ref, m_ref, lut_re_ref, lut_a_ref,
                          s_ref, o_ref, *, scale, causal, lq, lk, lk_valid,
-                         bq, bk, qmax, index_mode, lookup):
+                         bq, bk, qmax, index_mode):
     """REXP fused variant: accumulate S and U = Σ e_int·v; epilogue applies
     α·inv² to U (beyond-paper — skips per-element σ requantization)."""
     kb = pl.program_id(3)
@@ -149,11 +150,12 @@ def _fused_sum_av_kernel(q_ref, k_ref, v_ref, m_ref, lut_re_ref, lut_a_ref,
     s = _block_logits(q_ref, k_ref, scale, causal, lq, lk, lk_valid, bq, bk)
     m = m_ref[0, 0]
     m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e_int = _rexp_e_int(s, m, lut_re_ref[0, :], index_mode, lookup)
+    e_int = _rexp_e_int(s, m, lut_re_ref[0, :], index_mode)
     e_f = e_int.astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     s_ref[0, 0] += jnp.sum(e_f, axis=-1)
     o_ref[0, 0] += jax.lax.dot_general(e_f, v, (((1,), (0,)), ((), ())),
+                                       precision=ATTN_PRECISION,
                                        preferred_element_type=jnp.float32)
 
     @pl.when(kb == nk - 1)
@@ -162,7 +164,7 @@ def _fused_sum_av_kernel(q_ref, k_ref, v_ref, m_ref, lut_re_ref, lut_a_ref,
         n_a = lut_a_ref.shape[1]
         rnd = jnp.round if index_mode == "round" else jnp.floor
         ja = jnp.clip(rnd(s_ref[0, 0] * inv).astype(jnp.int32), 0, n_a - 1)
-        alpha = kernel_lookup(lut_a_ref[0, :], ja, lookup)
+        alpha = select_lookup(lut_a_ref[0, :], ja)
         o_ref[0, 0] *= (alpha.astype(jnp.float32) * inv * inv)[:, None]
 
 
@@ -173,7 +175,7 @@ def _fused_sum_av_kernel(q_ref, k_ref, v_ref, m_ref, lut_re_ref, lut_a_ref,
 
 def _rexp_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_re_ref, lut_a_ref,
                     o_ref, *, scale, causal, lq, lk, lk_valid, bq, bk, qmax,
-                    index_mode, lookup):
+                    index_mode):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -184,13 +186,14 @@ def _rexp_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_re_ref, lut_a_ref,
     s = _block_logits(q_ref, k_ref, scale, causal, lq, lk, lk_valid, bq, bk)
     m = m_ref[0, 0]
     m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e_int = _rexp_e_int(s, m, lut_re_ref[0, :], index_mode, lookup)
+    e_int = _rexp_e_int(s, m, lut_re_ref[0, :], index_mode)
 
     # Faithful Algorithm 1: per-element w-bit σ requantization, THEN ·v.
     sigma_int = rexp_sigma(e_int, s_ref[0, 0][:, None], lut_a_ref[0, :],
-                           qmax, index_mode, lookup)
+                           qmax, index_mode)
     v = v_ref[0, 0].astype(jnp.float32)
     o_ref[0, 0] += jax.lax.dot_general(sigma_int, v, (((1,), (0,)), ((), ())),
+                                       precision=ATTN_PRECISION,
                                        preferred_element_type=jnp.float32)
 
     @pl.when(kb == nk - 1)
@@ -200,7 +203,7 @@ def _rexp_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_re_ref, lut_a_ref,
 
 def _lut2d_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_e_ref, lut_s_ref,
                      o_ref, *, scale, causal, lq, lk, lk_valid, bq, bk, qmax,
-                     exp_step, scale_ex, scale_sum, index_mode, lookup):
+                     exp_step, scale_ex, scale_sum, index_mode):
     kb = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -211,7 +214,7 @@ def _lut2d_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_e_ref, lut_s_ref,
     s = _block_logits(q_ref, k_ref, scale, causal, lq, lk, lk_valid, bq, bk)
     m = m_ref[0, 0]
     m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e_int = _lut2d_e_int(s, m, lut_e_ref[0, :], exp_step, index_mode, lookup)
+    e_int = _lut2d_e_int(s, m, lut_e_ref[0, :], exp_step, index_mode)
 
     sigma_int = lut2d_sigma_int(e_int, s_ref[0, 0][:, None], lut_s_ref[...],
                                 qmax, scale_ex, scale_sum, index_mode)
@@ -219,7 +222,7 @@ def _lut2d_av_kernel(q_ref, k_ref, v_ref, m_ref, s_ref, lut_e_ref, lut_s_ref,
     v = v_ref[0, 0].astype(jnp.float32)
     o_ref[0, 0] += jax.lax.dot_general(
         sigma_int.astype(jnp.float32), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=ATTN_PRECISION, preferred_element_type=jnp.float32)
 
     @pl.when(kb == nk - 1)
     def _dequant():
@@ -311,7 +314,6 @@ def lut_attention_pallas(
     causal: bool = False,
     scale: float | None = None,
     index_mode: str = "round",
-    lookup: str = "select",
     fused_requant: bool = False,      # REXP only: 2-pass beyond-paper variant
     block_q: int = 256,
     block_k: int = 256,
@@ -364,7 +366,7 @@ def lut_attention_pallas(
     if method == "rexp" and fused_requant:
         s_sum, out = pl.pallas_call(
             functools.partial(_fused_sum_av_kernel, qmax=qmax,
-                              index_mode=index_mode, lookup=lookup, **geom),
+                              index_mode=index_mode, **geom),
             grid=grid,
             in_specs=[q_spec, k_spec, v_spec, m_spec, _lut_spec(lut_main),
                       _lut_spec(lut_a)],
@@ -379,7 +381,7 @@ def lut_attention_pallas(
     # Pass 2: Σ e_int.
     s_sum = pl.pallas_call(
         functools.partial(_sum_kernel, method=method, exp_step=exp_step,
-                          index_mode=index_mode, lookup=lookup, **geom),
+                          index_mode=index_mode, **geom),
         grid=grid,
         in_specs=[q_spec, k_spec, m_spec, _lut_spec(lut_main)],
         out_specs=m_spec,
@@ -391,7 +393,7 @@ def lut_attention_pallas(
     if method == "rexp":
         out = pl.pallas_call(
             functools.partial(_rexp_av_kernel, qmax=qmax,
-                              index_mode=index_mode, lookup=lookup, **geom),
+                              index_mode=index_mode, **geom),
             grid=grid,
             in_specs=[q_spec, k_spec, v_spec, m_spec, m_spec,
                       _lut_spec(lut_main), _lut_spec(lut_a)],
@@ -404,7 +406,7 @@ def lut_attention_pallas(
             functools.partial(_lut2d_av_kernel, qmax=qmax, exp_step=exp_step,
                               scale_ex=tables.scale_ex,
                               scale_sum=tables.scale_sum,
-                              index_mode=index_mode, lookup=lookup, **geom),
+                              index_mode=index_mode, **geom),
             grid=grid,
             in_specs=[q_spec, k_spec, v_spec, m_spec, m_spec,
                       _lut_spec(lut_main), _lut_spec(lut_sig)],

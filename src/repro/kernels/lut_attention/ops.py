@@ -71,22 +71,13 @@ from repro.core import lut_builder
 from repro.core.lut_softmax import inv_scale
 from repro.core.policies import SoftmaxPolicy
 from repro.core import lut_softmax as _core
-from repro.kernels.common import dequant_scope, kernel_lookup
+from repro.kernels.common import ATTN_PRECISION, dequant_scope, kernel_lookup
 from repro.kernels.lut_attention import ref as _ref
 from repro.kernels.lut_attention.lut_attention import lut_attention_pallas
 from repro.kernels.lut_attention.paged_decode import paged_decode_attention
 from repro.kernels.lut_attention.paged_prefill import paged_prefill_attention
 
 Array = jax.Array
-
-
-def _kernel_lookup(policy: SoftmaxPolicy, interpret: bool) -> str:
-    """The in-kernel LUT read.  Mosaic lowers no 1-D gather, so compiled
-    kernels read their tables through select chains; interpret mode keeps
-    the policy's gather.  Both return the same table entries."""
-    if not interpret:
-        return "select"
-    return "gather" if policy.lookup_impl == "gather" else "select"
 
 
 def _tables_for(policy: SoftmaxPolicy):
@@ -134,7 +125,8 @@ def _chunk_mask(q0: Array | int, k0: Array | int, bq: int, bk: int,
 def _grouped_logits(qc: Array, kc: Array, scale: float) -> Array:
     """q (B,KVH,G,bq,D) × k (B,KVH,bk,D) → (B,KVH,G,bq,bk) f32."""
     return jnp.einsum("bngqd,bnkd->bngqk", qc.astype(jnp.float32),
-                      kc.astype(jnp.float32)) * scale
+                      kc.astype(jnp.float32),
+                      precision=ATTN_PRECISION) * scale
 
 
 def lut_attention_blocked(
@@ -212,7 +204,8 @@ def lut_attention_blocked(
                 l = l * corr + jnp.sum(p, axis=-1)
                 acc = (acc * corr[..., None]
                        + jnp.einsum("bngqk,bnkd->bngqd", p,
-                                    vc.astype(jnp.float32)))
+                                    vc.astype(jnp.float32),
+                                    precision=ATTN_PRECISION))
                 return (m_new, l, acc), None
 
             m0 = jnp.full((b, kvh, g, bq), -jnp.inf, jnp.float32)
@@ -267,7 +260,8 @@ def lut_attention_blocked(
                     e = e_int_of(s, m_safe).astype(jnp.float32)
                 ssum = ssum + jnp.sum(e, axis=-1)
                 u = u + jnp.einsum("bngqk,bnkd->bngqd", e,
-                                   vc.astype(jnp.float32))
+                                   vc.astype(jnp.float32),
+                                   precision=ATTN_PRECISION)
                 return (ssum, u), None
 
             s0 = jnp.zeros((b, kvh, g, bq), jnp.float32)
@@ -325,9 +319,8 @@ def lut_attention(
         tables = _tables_for(policy)
         return lut_attention_pallas(
             q, k, v, tables, method=policy.impl, causal=causal, scale=scale,
-            index_mode=policy.index_mode,
-            lookup=_kernel_lookup(policy, interpret),
-            fused_requant=fused_requant, interpret=interpret)
+            index_mode=policy.index_mode, fused_requant=fused_requant,
+            interpret=interpret)
     if backend == "blocked":
         return lut_attention_blocked(q, k, v, policy, causal=causal,
                                      scale=scale, kv_len=kv_len,
@@ -375,7 +368,7 @@ def _grouped_pv(p: Array, v: Array) -> Array:
     kvh = v.shape[1]
     g = h // kvh
     out = jnp.einsum("bngqk,bnkd->bngqd", p.reshape(b, kvh, g, lq, lk),
-                     v.astype(jnp.float32))
+                     v.astype(jnp.float32), precision=ATTN_PRECISION)
     return out.reshape(b, h, lq, -1)
 
 
@@ -546,7 +539,6 @@ def lut_attention_paged_prefill(
             q, k_pages, v_pages, block_tables, q_start, kv_lens,
             _tables_for(policy), method=policy.impl, scale=scale,
             index_mode=policy.index_mode,
-            lookup=_kernel_lookup(policy, resolved == "pallas_interpret"),
             interpret=resolved == "pallas_interpret",
             k_scales=k_scales, v_scales=v_scales)
     if k_scales is not None:
@@ -689,7 +681,6 @@ def lut_attention_paged_decode(
         return paged_decode_attention(
             q, k_pages, v_pages, block_tables, kv_lens, _tables_for(policy),
             method=policy.impl, scale=scale, index_mode=policy.index_mode,
-            lookup=_kernel_lookup(policy, resolved == "pallas_interpret"),
             interpret=resolved == "pallas_interpret",
             k_scales=k_scales, v_scales=v_scales)
     if k_scales is not None:

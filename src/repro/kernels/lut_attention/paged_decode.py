@@ -54,9 +54,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
 from repro.core.lut_softmax import inv_scale
-from repro.kernels.common import (NEG_INF, dequant_scope, lut2d_sigma_int,
-                                  policy_e_terms, policy_kernel_tables,
-                                  rexp_sigma)
+from repro.kernels.common import (ATTN_PRECISION, NEG_INF, dequant_scope,
+                                  lut2d_sigma_int, policy_e_terms,
+                                  policy_kernel_tables, rexp_sigma)
 
 Array = jax.Array
 
@@ -111,6 +111,7 @@ def _page_logits(q_ref, k_ref, kl_ref, scale, page_size, ks_ref=None):
     q = q_ref[0, 0].astype(jnp.float32)          # (G, Dh)
     k = _page_rows(k_ref, ks_ref)                # (ps, Dh)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=ATTN_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(pos < kl_ref[b], s, NEG_INF)
@@ -152,32 +153,30 @@ def _pg_rowmax_kernel_int8(bt_ref, kl_ref, q_ref, k_ref, ks_ref, m_ref, *,
 # ---------------------------------------------------------------------------
 
 
-def _accum_sum(s, m_ref, lut_ref, s_ref, method, exp_step, index_mode,
-               lookup):
+def _accum_sum(s, m_ref, lut_ref, s_ref, method, exp_step, index_mode):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     m = m_ref[0, 0]
     m = jnp.where(jnp.isfinite(m), m, 0.0)
-    e = policy_e_terms(s, m, lut_ref[0, :], method, exp_step, index_mode,
-                       lookup)
+    e = policy_e_terms(s, m, lut_ref[0, :], method, exp_step, index_mode)
     with dequant_scope():  # f32-exact integer Σ accumulator
         s_ref[0, 0] += jnp.sum(e.astype(jnp.float32), axis=-1, keepdims=True)
 
 
 def _pg_sum_kernel(bt_ref, kl_ref, q_ref, k_ref, m_ref, lut_ref, s_ref, *,
-                   scale, page_size, method, exp_step, index_mode, lookup):
+                   scale, page_size, method, exp_step, index_mode):
     _accum_sum(_page_logits(q_ref, k_ref, kl_ref, scale, page_size),
-               m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
+               m_ref, lut_ref, s_ref, method, exp_step, index_mode)
 
 
 def _pg_sum_kernel_int8(bt_ref, kl_ref, q_ref, k_ref, ks_ref, m_ref, lut_ref,
                         s_ref, *, scale, page_size, method, exp_step,
-                        index_mode, lookup):
+                        index_mode):
     _accum_sum(_page_logits(q_ref, k_ref, kl_ref, scale, page_size,
                             ks_ref=ks_ref),
-               m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
+               m_ref, lut_ref, s_ref, method, exp_step, index_mode)
 
 
 # ---------------------------------------------------------------------------
@@ -186,8 +185,7 @@ def _pg_sum_kernel_int8(bt_ref, kl_ref, q_ref, k_ref, ks_ref, m_ref, lut_ref,
 
 
 def _accum_weight(s, v, m_ref, s_ref, lut_main_ref, lut_aux_ref, o_ref,
-                  method, qmax, exp_step, scale_ex, scale_sum, index_mode,
-                  lookup):
+                  method, qmax, exp_step, scale_ex, scale_sum, index_mode):
     """Accumulate out += σ(s, m, S) @ V_page with the policy's per-element
     weights — REXP re-quantizes σ_int per element (Algorithm 1 line 11),
     2D-LUT reads LUT_σ[i(e), j(S)] (Algorithm 2), exact divides by S."""
@@ -198,14 +196,14 @@ def _accum_weight(s, v, m_ref, s_ref, lut_main_ref, lut_aux_ref, o_ref,
     m = m_ref[0, 0]
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     e = policy_e_terms(s, m, lut_main_ref[0, :], method, exp_step,
-                       index_mode, lookup)
+                       index_mode)
     s_tot = s_ref[0, 0]  # (R, 1) global Σ from pass 2
 
     if method == "exact":
         w = e / jnp.maximum(s_tot, jnp.finfo(jnp.float32).tiny)
     elif method == "rexp":
-        w = rexp_sigma(e, s_tot, lut_aux_ref[0, :], qmax, index_mode,
-                       lookup) * inv_scale(qmax)
+        w = rexp_sigma(e, s_tot, lut_aux_ref[0, :], qmax,
+                       index_mode) * inv_scale(qmax)
     else:  # lut2d
         sigma_int = lut2d_sigma_int(e, s_tot, lut_aux_ref[...], qmax,
                                     scale_ex, scale_sum, index_mode)
@@ -214,28 +212,28 @@ def _accum_weight(s, v, m_ref, s_ref, lut_main_ref, lut_aux_ref, o_ref,
 
     o_ref[0, 0] += jax.lax.dot_general(
         w.astype(jnp.float32), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=ATTN_PRECISION, preferred_element_type=jnp.float32)
 
 
 def _pg_weight_kernel(bt_ref, kl_ref, q_ref, k_ref, v_ref, m_ref, s_ref,
                       lut_main_ref, lut_aux_ref, o_ref, *, scale, page_size,
-                      method, qmax, exp_step, scale_ex, scale_sum, index_mode,
-                      lookup):
+                      method, qmax, exp_step, scale_ex, scale_sum,
+                      index_mode):
     _accum_weight(_page_logits(q_ref, k_ref, kl_ref, scale, page_size),
                   _page_rows(v_ref, None), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
-                  scale_sum, index_mode, lookup)
+                  scale_sum, index_mode)
 
 
 def _pg_weight_kernel_int8(bt_ref, kl_ref, q_ref, k_ref, ks_ref, v_ref,
                            vs_ref, m_ref, s_ref, lut_main_ref, lut_aux_ref,
                            o_ref, *, scale, page_size, method, qmax, exp_step,
-                           scale_ex, scale_sum, index_mode, lookup):
+                           scale_ex, scale_sum, index_mode):
     _accum_weight(_page_logits(q_ref, k_ref, kl_ref, scale, page_size,
                                ks_ref=ks_ref),
                   _page_rows(v_ref, vs_ref), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
-                  scale_sum, index_mode, lookup)
+                  scale_sum, index_mode)
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +386,6 @@ def paged_decode_attention(
     method: str = "exact",          # 'exact' | 'rexp' | 'lut2d'
     scale: float | None = None,
     index_mode: str = "round",
-    lookup: str = "select",
     interpret: bool | None = None,
     k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None,
@@ -410,12 +407,10 @@ def paged_decode_attention(
     Numerics match ``ops.lut_attention_decode_varlen`` on the gathered
     view: identical integer pipeline (bins, e_int, Σ, σ_int); the final
     f32 V-contraction accumulates per page, so outputs agree to f32
-    roundoff on the CPU (the parity suite pins the tolerance).  On a
-    TPU both dots run at Mosaic's default precision, one bf16 pass over
-    f32 operands (as XLA's default matmuls do), so there a logit carries
-    bf16 rounding and a LUT bin may flip against an f32 reference;
-    ``chip_smoke.py`` holds the compiled kernel to the reference on
-    logits that are exact in bf16.
+    roundoff.  Both dots run at ``ATTN_PRECISION`` (f32), like the
+    reference's, so this holds on a TPU too: there the compiled kernel
+    may part from the reference only in a row with a logit at a LUT
+    bin's rounding edge (``chip_smoke.py`` checks both).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -467,7 +462,7 @@ def paged_decode_attention(
     # Pass 2: global Σ of the policy's numerators.
     s_sum = pl.pallas_call(
         functools.partial(sum_k, method=method, exp_step=exp_step,
-                          index_mode=index_mode, lookup=lookup, **geom),
+                          index_mode=index_mode, **geom),
         grid_spec=spec([q_spec] + k_in + [acc_spec, _lut_spec(lut_main)],
                        acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
@@ -479,7 +474,7 @@ def paged_decode_attention(
         functools.partial(weight_k, method=method, qmax=qmax,
                           exp_step=exp_step, scale_ex=scale_ex,
                           scale_sum=scale_sum, index_mode=index_mode,
-                          lookup=lookup, **geom),
+                          **geom),
         grid_spec=spec([q_spec] + k_in + v_in + [acc_spec, acc_spec,
                         _lut_spec(lut_main), _lut_spec(lut_aux)],
                        o_spec),

@@ -62,7 +62,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
-from repro.kernels.common import NEG_INF, policy_kernel_tables
+from repro.kernels.common import (ATTN_PRECISION, NEG_INF,
+                                  policy_kernel_tables)
 from repro.kernels.lut_attention.paged_decode import (_accum_rowmax,
                                                       _accum_sum,
                                                       _accum_weight,
@@ -96,6 +97,7 @@ def _chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size, chunk,
     q = q_ref[0, 0].astype(jnp.float32)          # (G·C, Dh)
     k = _page_rows(k_ref, ks_ref)                # (ps, Dh)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=ATTN_PRECISION,
                             preferred_element_type=jnp.float32) * scale
     pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     qi = qs_ref[b] + jax.lax.rem(
@@ -122,41 +124,41 @@ def _pf_rowmax_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
 
 def _pf_sum_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, m_ref, lut_ref,
                    s_ref, *, scale, page_size, chunk, method, exp_step,
-                   index_mode, lookup):
+                   index_mode):
     _accum_sum(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size,
                              chunk),
-               m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
+               m_ref, lut_ref, s_ref, method, exp_step, index_mode)
 
 
 def _pf_sum_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref, m_ref,
                         lut_ref, s_ref, *, scale, page_size, chunk, method,
-                        exp_step, index_mode, lookup):
+                        exp_step, index_mode):
     _accum_sum(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale, page_size,
                              chunk, ks_ref=ks_ref),
-               m_ref, lut_ref, s_ref, method, exp_step, index_mode, lookup)
+               m_ref, lut_ref, s_ref, method, exp_step, index_mode)
 
 
 def _pf_weight_kernel(bt_ref, kl_ref, qs_ref, q_ref, k_ref, v_ref, m_ref,
                       s_ref, lut_main_ref, lut_aux_ref, o_ref, *, scale,
                       page_size, chunk, method, qmax, exp_step, scale_ex,
-                      scale_sum, index_mode, lookup):
+                      scale_sum, index_mode):
     _accum_weight(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
                                 page_size, chunk),
                   _page_rows(v_ref, None), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
-                  scale_sum, index_mode, lookup)
+                  scale_sum, index_mode)
 
 
 def _pf_weight_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
                            v_ref, vs_ref, m_ref, s_ref, lut_main_ref,
                            lut_aux_ref, o_ref, *, scale, page_size, chunk,
                            method, qmax, exp_step, scale_ex, scale_sum,
-                           index_mode, lookup):
+                           index_mode):
     _accum_weight(_chunk_logits(q_ref, k_ref, kl_ref, qs_ref, scale,
                                 page_size, chunk, ks_ref=ks_ref),
                   _page_rows(v_ref, vs_ref), m_ref, s_ref, lut_main_ref,
                   lut_aux_ref, o_ref, method, qmax, exp_step, scale_ex,
-                  scale_sum, index_mode, lookup)
+                  scale_sum, index_mode)
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +275,6 @@ def paged_prefill_attention(
     method: str = "exact",          # 'exact' | 'rexp' | 'lut2d'
     scale: float | None = None,
     index_mode: str = "round",
-    lookup: str = "select",
     interpret: bool | None = None,
     k_scales: Array | None = None,  # (num_pages, 1, KVH·page_size) f32
     v_scales: Array | None = None,
@@ -293,14 +294,12 @@ def paged_prefill_attention(
     Numerics match ``ops.lut_attention_prefill_varlen`` on the gathered
     view: identical integer pipeline (bins, e_int, Σ, σ_int); the final
     f32 V-contraction accumulates per page, so outputs agree to f32
-    roundoff on the CPU (the parity suite pins the tolerance).  On a
-    TPU both dots run at Mosaic's default precision, one bf16 pass over
-    f32 operands (as XLA's default matmuls do), so there a logit carries
-    bf16 rounding and a LUT bin may flip against an f32 reference;
-    ``chip_smoke.py`` holds the compiled kernel to the reference on
-    logits that are exact in bf16.  Rows past a chunk's valid length
-    carry the same defined-but-garbage values as the oracle's (the
-    engine discards them).
+    roundoff.  Both dots run at ``ATTN_PRECISION`` (f32), like the
+    reference's, so this holds on a TPU too: there the compiled kernel
+    may part from the reference only in a row with a logit at a LUT
+    bin's rounding edge (``chip_smoke.py`` checks both).  Rows past a
+    chunk's valid length carry the same defined-but-garbage values as
+    the oracle's (the engine discards them).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -352,7 +351,7 @@ def paged_prefill_attention(
     # Pass 2: global Σ of the policy's numerators.
     s_sum = pl.pallas_call(
         functools.partial(sum_k, method=method, exp_step=exp_step,
-                          index_mode=index_mode, lookup=lookup, **geom),
+                          index_mode=index_mode, **geom),
         grid_spec=spec([q_spec] + k_in + [acc_spec, _lut_spec(lut_main)],
                        acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, 1), jnp.float32),
@@ -364,7 +363,7 @@ def paged_prefill_attention(
         functools.partial(weight_k, method=method, qmax=qmax,
                           exp_step=exp_step, scale_ex=scale_ex,
                           scale_sum=scale_sum, index_mode=index_mode,
-                          lookup=lookup, **geom),
+                          **geom),
         grid_spec=spec([q_spec] + k_in + v_in + [acc_spec, acc_spec,
                         _lut_spec(lut_main), _lut_spec(lut_aux)],
                        o_spec),

@@ -6,6 +6,8 @@ Semantics = naive attention with the core LUT softmax in the middle:
     σ      = softmax_<method>(logits)        # repro.core semantics
     out    = σ @ v
 
+both products at ``kernels.common.ATTN_PRECISION`` (f32 on any backend).
+
 The kernels block the K dimension, so the final f32 contraction
 accumulates in a different order than the naive oracle — tests use
 ``assert_allclose`` with a tight tolerance for ``out`` but require the
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
 from repro.core import lut_softmax as _core
+from repro.kernels.common import ATTN_PRECISION
 
 Array = jax.Array
 
@@ -30,7 +33,7 @@ def _logits(q: Array, k: Array, scale: float, causal: bool) -> Array:
     g = h // kvh
     kx = jnp.repeat(k, g, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   kx.astype(jnp.float32)) * scale
+                   kx.astype(jnp.float32), precision=ATTN_PRECISION) * scale
     if causal:
         lk = k.shape[2]
         qi = jnp.arange(lq)[:, None] + (lk - lq)  # right-aligned queries
@@ -59,22 +62,26 @@ def lut_attention_ref(
 
     if method == "exact":
         p = _core.softmax_exact(s, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p, vx)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vx,
+                          precision=ATTN_PRECISION)
     if method == "rexp":
         assert isinstance(tables, RexpTables)
         if not fused_requant:
             p = _core.softmax_rexp(s, tables, axis=-1, index_mode=index_mode)
-            return jnp.einsum("bhqk,bhkd->bhqd", p, vx)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, vx,
+                              precision=ATTN_PRECISION)
         qmax = tables.precision.qmax
         inv = _core.inv_scale(qmax)
         e_int = _core.rexp_exp_int(s, tables, axis=-1, index_mode=index_mode)
         ssum = jnp.sum(e_int.astype(jnp.float32), axis=-1, keepdims=True)
         ja = _core.rexp_alpha_index(ssum, tables, index_mode)
         alpha = jnp.take(jnp.asarray(tables.lut_alpha, jnp.int32), ja, axis=0)
-        u = jnp.einsum("bhqk,bhkd->bhqd", e_int.astype(jnp.float32), vx)
+        u = jnp.einsum("bhqk,bhkd->bhqd", e_int.astype(jnp.float32), vx,
+                       precision=ATTN_PRECISION)
         return u * (alpha.astype(jnp.float32) * inv * inv)
     if method == "lut2d":
         assert isinstance(tables, Lut2DTables)
         p = _core.softmax_lut2d(s, tables, axis=-1, index_mode=index_mode)
-        return jnp.einsum("bhqk,bhkd->bhqd", p, vx)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vx,
+                          precision=ATTN_PRECISION)
     raise ValueError(f"unknown method {method!r}")

@@ -24,7 +24,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 from repro.core import lut_builder
-from repro.kernels.common import dequant_scope, kernel_lookup
+from repro.kernels.common import ATTN_PRECISION, dequant_scope, kernel_lookup
 from repro.core.lut_softmax import inv_scale
 from repro.core.policies import SoftmaxPolicy
 
@@ -81,8 +81,8 @@ def lut_decode_sharded(
         ki = idx * l_shard + jnp.arange(l_shard)
         valid = (ki < kv_len_)[None, None, None, :]           # (1,1,1,l)
         qg = q_.reshape(q_.shape[0], kvh, g, lq, d).astype(jnp.float32)
-        s = jnp.einsum("bngqd,bnkd->bngqk", qg,
-                       k_.astype(jnp.float32)) * scale
+        s = jnp.einsum("bngqd,bnkd->bngqk", qg, k_.astype(jnp.float32),
+                       precision=ATTN_PRECISION) * scale
         s = jnp.where(valid[:, :, None], s, -jnp.inf)
         m_loc = jnp.max(s, axis=-1)
         m = jax.lax.pmax(m_loc, seq_axis)
@@ -93,7 +93,8 @@ def lut_decode_sharded(
                           jnp.exp(s - m_safe[..., None]), 0.0)
             l_loc = jnp.sum(p, axis=-1)
             u_loc = jnp.einsum("bngqk,bnkd->bngqd", p,
-                               v_.astype(jnp.float32))
+                               v_.astype(jnp.float32),
+                               precision=ATTN_PRECISION)
             lsum = jax.lax.psum(l_loc, seq_axis)
             u = jax.lax.psum(u_loc, seq_axis)
             out = u / jnp.maximum(lsum, 1e-30)[..., None]
@@ -107,7 +108,8 @@ def lut_decode_sharded(
                 e = e.astype(jnp.float32)
             s_loc = jnp.sum(e, axis=-1)
             u_loc = jnp.einsum("bngqk,bnkd->bngqd", e,
-                               v_.astype(jnp.float32))
+                               v_.astype(jnp.float32),
+                               precision=ATTN_PRECISION)
             ssum = jax.lax.psum(s_loc, seq_axis)
             u = jax.lax.psum(u_loc, seq_axis)
             inv = inv_scale(qmax)
