@@ -242,8 +242,6 @@ def bench_closed_loop_async(n_requests: int = 24, seed: int = 0) -> dict:
         "pipelined_tok_s": round(useful / t_pipe, 1),
         "speedup": round(t_sync / t_pipe, 3),
         "pipeline_depth": engines["pipelined"].depth,
-        "harvest_wait_s": round(
-            engines["pipelined"].stats.harvest_wait_s, 3),
     }
 
 

@@ -48,6 +48,19 @@ def dequant_scope():
     """Scope sanctioning an intentional int→float dequant/accumulate."""
     return jax.named_scope(LUT_DEQUANT_TAG)
 
+
+#: the paged kernels' passes, in launch order: the ``PassSpec`` names of
+#: their guard declarations and, via :func:`pass_name`, the ``name=`` of
+#: each ``pallas_call`` (the op name a profiler trace shows)
+PAGED_PASSES = ("rowmax", "sum", "weight")
+
+
+def pass_name(kernel: str, pass_: str) -> str:
+    """Device name of one pass: ``<KernelSpec.name>_<PassSpec.name>``,
+    e.g. ``paged_decode_int8_rowmax``."""
+    return f"{kernel}_{pass_}"
+
+
 #: per-kernel VMEM working-set budget (bytes): the scoped VMEM limit the
 #: kernels compile under.  They pass no ``vmem_limit_bytes``, so Mosaic
 #: applies the compiler's default, 16 MiB on TPU v5e — an ahead-of-time

@@ -54,9 +54,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
 from repro.core.lut_softmax import inv_scale
-from repro.kernels.common import (ATTN_PRECISION, NEG_INF, dequant_scope,
-                                  lut2d_sigma_int, policy_e_terms,
-                                  policy_kernel_tables, rexp_sigma)
+from repro.kernels.common import (ATTN_PRECISION, NEG_INF, PAGED_PASSES,
+                                  dequant_scope, lut2d_sigma_int, pass_name,
+                                  policy_e_terms, policy_kernel_tables,
+                                  rexp_sigma)
 
 Array = jax.Array
 
@@ -277,6 +278,12 @@ def _row_specs(rows, dh):
     return q_spec, acc_spec, q_spec
 
 
+def kernel_name(quantized: bool) -> str:
+    """``KernelSpec.name`` of the f32- or int8-pool variant; its passes
+    run as ``pass_name(kernel_name(quantized), p)``."""
+    return "paged_decode_int8" if quantized else "paged_decode"
+
+
 def _grid_specs(g, dh, page_size):
     """The decode dispatch's BlockSpecs — single source for the launcher
     and for ``kernel_spec`` (the static guard's declaration)."""
@@ -329,24 +336,26 @@ def _build_kernel_spec(geom, quantized):
     t_main = Operand("lut_main", lut_main.shape, _lut_spec(lut_main), "int32")
     t_aux = Operand("lut_aux", lut_aux.shape, _lut_spec(lut_aux), "int32")
 
+    rowmax, sum_, weight = PAGED_PASSES
     passes = (
-        PassSpec("rowmax", grid, (q,) + kk, (m,), scalar_prefetch=prefetch),
-        PassSpec("sum", grid, (q,) + kk + (m, t_main), (s,),
+        PassSpec(rowmax, grid, (q,) + kk, (m,), scalar_prefetch=prefetch),
+        PassSpec(sum_, grid, (q,) + kk + (m, t_main), (s,),
                  scalar_prefetch=prefetch, sigma_acc=True,
                  acc_dtype="float32",
                  notes="integer Σ accumulated f32-exact in the resident ref"),
-        PassSpec("weight", grid, (q,) + kk + vvv + (m, s, t_main, t_aux),
+        PassSpec(weight, grid, (q,) + kk + vvv + (m, s, t_main, t_aux),
                  (o,), scalar_prefetch=prefetch),
     )
     if quantized:
         return KernelSpec(
-            name="paged_decode_int8", module=__name__, kind="pallas",
+            name=kernel_name(quantized), module=__name__, kind="pallas",
             passes=passes,
             notes="int8 pool variant: pages stream as int8 with per-token "
                   "f32 scale blocks riding the same block-table "
                   "indirection; dequant in VMEM under dequant_scope")
     return KernelSpec(
-        name="paged_decode", module=__name__, kind="pallas", passes=passes,
+        name=kernel_name(quantized), module=__name__, kind="pallas",
+        passes=passes,
         notes="streams pages from the pool via scalar-prefetched block "
               "tables; one page DMA per grid step")
 
@@ -450,6 +459,8 @@ def paged_decode_attention(
     rowmax_k = _pg_rowmax_kernel_int8 if quantized else _pg_rowmax_kernel
     sum_k = _pg_sum_kernel_int8 if quantized else _pg_sum_kernel
     weight_k = _pg_weight_kernel_int8 if quantized else _pg_weight_kernel
+    rowmax_name, sum_name, weight_name = (
+        pass_name(kernel_name(quantized), p) for p in PAGED_PASSES)
 
     # Pass 1: global row max, accumulated online over the page chunks.
     m = pl.pallas_call(
@@ -457,6 +468,7 @@ def paged_decode_attention(
         grid_spec=spec([q_spec] + k_in, acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         interpret=interpret,
+        name=rowmax_name,
     )(block_tables, kv_lens, qg, *k_ops)
 
     # Pass 2: global Σ of the policy's numerators.
@@ -467,6 +479,7 @@ def paged_decode_attention(
                        acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, 1), jnp.float32),
         interpret=interpret,
+        name=sum_name,
     )(block_tables, kv_lens, qg, *k_ops, m, lut_main)
 
     # Pass 3: per-element σ · V, accumulated page by page.
@@ -480,6 +493,7 @@ def paged_decode_attention(
                        o_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, dh), jnp.float32),
         interpret=interpret,
+        name=weight_name,
     )(block_tables, kv_lens, qg, *k_ops, *v_ops, m, s_sum, lut_main,
       lut_aux)
 

@@ -62,8 +62,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut_builder import Lut2DTables, RexpTables
-from repro.kernels.common import (ATTN_PRECISION, NEG_INF,
-                                  policy_kernel_tables)
+from repro.kernels.common import (ATTN_PRECISION, NEG_INF, PAGED_PASSES,
+                                  pass_name, policy_kernel_tables)
 from repro.kernels.lut_attention.paged_decode import (_accum_rowmax,
                                                       _accum_sum,
                                                       _accum_weight,
@@ -166,6 +166,12 @@ def _pf_weight_kernel_int8(bt_ref, kl_ref, qs_ref, q_ref, k_ref, ks_ref,
 # ---------------------------------------------------------------------------
 
 
+def kernel_name(quantized: bool) -> str:
+    """``KernelSpec.name`` of the f32- or int8-pool variant; its passes
+    run as ``pass_name(kernel_name(quantized), p)``."""
+    return "paged_prefill_int8" if quantized else "paged_prefill"
+
+
 def _grid_specs(g, c, dh, page_size):
     """The prefill dispatch's BlockSpecs — single source for the launcher
     and for ``kernel_spec`` (the static guard's declaration).  A cell
@@ -221,24 +227,26 @@ def _build_kernel_spec(geom, quantized):
     t_main = Operand("lut_main", lut_main.shape, _lut_spec(lut_main), "int32")
     t_aux = Operand("lut_aux", lut_aux.shape, _lut_spec(lut_aux), "int32")
 
+    rowmax, sum_, weight = PAGED_PASSES
     passes = (
-        PassSpec("rowmax", grid, (q,) + kk, (m,), scalar_prefetch=prefetch),
-        PassSpec("sum", grid, (q,) + kk + (m, t_main), (s,),
+        PassSpec(rowmax, grid, (q,) + kk, (m,), scalar_prefetch=prefetch),
+        PassSpec(sum_, grid, (q,) + kk + (m, t_main), (s,),
                  scalar_prefetch=prefetch, sigma_acc=True,
                  acc_dtype="float32",
                  notes="integer Σ accumulated f32-exact in the resident ref"),
-        PassSpec("weight", grid, (q,) + kk + vvv + (m, s, t_main, t_aux),
+        PassSpec(weight, grid, (q,) + kk + vvv + (m, s, t_main, t_aux),
                  (o,), scalar_prefetch=prefetch),
     )
     if quantized:
         return KernelSpec(
-            name="paged_prefill_int8", module=__name__, kind="pallas",
+            name=kernel_name(quantized), module=__name__, kind="pallas",
             passes=passes,
             notes="int8 pool variant of the chunked prefill: pages stream "
                   "as int8 with per-token f32 scale blocks; dequant in "
                   "VMEM under dequant_scope")
     return KernelSpec(
-        name="paged_prefill", module=__name__, kind="pallas", passes=passes,
+        name=kernel_name(quantized), module=__name__, kind="pallas",
+        passes=passes,
         notes="chunked prefill streaming pages from the pool; causal "
               "frontier handled per element via prefetched q_start")
 
@@ -339,6 +347,8 @@ def paged_prefill_attention(
     rowmax_k = _pf_rowmax_kernel_int8 if quantized else _pf_rowmax_kernel
     sum_k = _pf_sum_kernel_int8 if quantized else _pf_sum_kernel
     weight_k = _pf_weight_kernel_int8 if quantized else _pf_weight_kernel
+    rowmax_name, sum_name, weight_name = (
+        pass_name(kernel_name(quantized), p) for p in PAGED_PASSES)
 
     # Pass 1: global row max, accumulated online over the page chunks.
     m = pl.pallas_call(
@@ -346,6 +356,7 @@ def paged_prefill_attention(
         grid_spec=spec([q_spec] + k_in, acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, 1), jnp.float32),
         interpret=interpret,
+        name=rowmax_name,
     )(block_tables, kv_lens, q_start, qg, *k_ops)
 
     # Pass 2: global Σ of the policy's numerators.
@@ -356,6 +367,7 @@ def paged_prefill_attention(
                        acc_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, 1), jnp.float32),
         interpret=interpret,
+        name=sum_name,
     )(block_tables, kv_lens, q_start, qg, *k_ops, m, lut_main)
 
     # Pass 3: per-element σ · V, accumulated page by page.
@@ -369,6 +381,7 @@ def paged_prefill_attention(
                        o_spec),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g * c, dh), jnp.float32),
         interpret=interpret,
+        name=weight_name,
     )(block_tables, kv_lens, q_start, qg, *k_ops, *v_ops, m, s_sum,
       lut_main, lut_aux)
 

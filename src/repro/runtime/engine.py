@@ -124,7 +124,7 @@ class RequestHandle:
 
     @property
     def ttft_s(self) -> float | None:
-        """Enqueue → first token, wall clock (None until sampled)."""
+        """Enqueue → first token, seconds (None until sampled)."""
         res = self._engine._results.get(self.id)
         if res is not None:
             return res.ttft_s
@@ -169,7 +169,7 @@ class GenerationResult:
     tokens: np.ndarray           # (n_generated,) int32
     finish_reason: str           # 'length' | 'eos'
     n_evictions: int
-    ttft_s: float | None = None  # enqueue → first token (wall clock)
+    ttft_s: float | None = None  # enqueue → first token, seconds
     prefix_hit_tokens: int = 0   # prompt tokens served from shared pages
 
 
@@ -190,7 +190,7 @@ class EngineStats:
     prefix_hit_tokens: int = 0   # prompt tokens never re-prefilled
     pages_shared: int = 0        # trie pages mapped into block tables
     cow_copies: int = 0          # copy-on-write page duplications
-    # longest wall-clock gap between consecutive decode-step COMPLETIONS
+    # longest gap between consecutive decode-step COMPLETIONS
     # (the worst inter-token wait a running slot observes; includes
     # whatever prefill work ran in between)
     max_decode_gap_s: float = 0.0
@@ -199,7 +199,6 @@ class EngineStats:
     speculative_wasted: int = 0  # dispatched slot-steps rolled back at EOS
     inflight_peak: int = 0       # max device steps simultaneously in flight
     queue_depth_peak: int = 0    # max waiting-queue depth observed
-    harvest_wait_s: float = 0.0  # host time blocked fetching step tokens
 
     @property
     def tokens(self) -> int:
@@ -430,7 +429,7 @@ class ServingEngine:
             id=rid, prompt=tuple(int(t) for t in np.asarray(prompt)),
             max_new_tokens=max_new_tokens, temperature=temperature,
             seed=seed, eos_id=eos_id))
-        self._t_added[rid] = time.time()
+        self._t_added[rid] = time.perf_counter()
         if on_token is not None:
             self._on_token[rid] = on_token
             self._n_streamed[rid] = 0
@@ -450,20 +449,13 @@ class ServingEngine:
         Returns requests finished this step.
         """
         finished: list[Sequence] = []
-        while self.scheduler.try_admit() is not None:
-            pass
-        self._run_pending_copies()
-        for seq, n in self.scheduler.plan_prefill(self.prefill_chunk,
-                                                  self.prefill_budget):
+        for seq, n in self._plan_prefill():
             if self._prefill_chunk_step(seq, n):
                 finished.append(seq)
-        if self.scheduler.decode_slots():
-            self.scheduler.grow_for_decode()
-            decode = self.scheduler.decode_slots()  # eviction may shrink it
-            if decode:
-                finished.extend(self._decode_step(decode))
-        self._sync_sched_stats()
-        return [self._record(seq) for seq in finished]
+        decode = self._plan_decode()
+        if decode:
+            finished.extend(self._decode_step(decode))
+        return self._finish_step(finished)
 
     def run(self, requests: SeqOf[tuple] | None = None,
             ) -> dict[int, GenerationResult]:
@@ -472,7 +464,7 @@ class ServingEngine:
         ``requests`` items are (prompt, max_new_tokens) pairs or dicts of
         :meth:`add_request` kwargs.
         """
-        t0 = time.time()
+        t0 = time.perf_counter()
         for r in requests or ():
             if isinstance(r, dict):
                 self.add_request(**r)
@@ -483,10 +475,43 @@ class ServingEngine:
         while self.has_work():
             for res in self.step():
                 out[res.request_id] = res
-        self.stats.wall_s += time.time() - t0
+        self.stats.wall_s += time.perf_counter() - t0
         return out
 
     # -- internals --------------------------------------------------------
+    #
+    # The driver thread's host phases run under profiler spans
+    # (``jax.profiler.TraceAnnotation``: recorded only while a trace is
+    # active, on the clock of the device ops): ``engine.plan``,
+    # ``engine.dispatch_chunk``, ``engine.dispatch_decode``,
+    # ``engine.harvest_wait`` and ``engine.resolve``.  They never nest in
+    # each other, so their summed durations are the host's self time.
+
+    def _plan_prefill(self) -> list[tuple[Sequence, int]]:
+        """Admission, queued copy-on-write page copies, and this step's
+        budgeted prefill chunks."""
+        with jax.profiler.TraceAnnotation("engine.plan"):
+            while self.scheduler.try_admit() is not None:
+                pass
+            self._run_pending_copies()
+            return self.scheduler.plan_prefill(self.prefill_chunk,
+                                               self.prefill_budget)
+
+    def _plan_decode(self) -> dict[int, Sequence]:
+        """The slots that decode this step, their pages grown for the
+        new token (eviction may shrink the set)."""
+        with jax.profiler.TraceAnnotation("engine.plan"):
+            if not self.scheduler.decode_slots():
+                return {}
+            self.scheduler.grow_for_decode()
+            return self.scheduler.decode_slots()
+
+    def _finish_step(self, finished: list[Sequence]
+                     ) -> list[GenerationResult]:
+        """Sync the scheduler's counters and record finished requests."""
+        with jax.profiler.TraceAnnotation("engine.resolve"):
+            self._sync_sched_stats()
+            return [self._record(seq) for seq in finished]
 
     @contextlib.contextmanager
     def _mesh_ctx(self):
@@ -581,7 +606,7 @@ class ServingEngine:
         # surfaces in the next decode completion, so chunked and
         # monolithic prefill are charged identically (dispatch-time gaps
         # would under-count the chunked mode's stall on async backends)
-        now = time.time()
+        now = time.perf_counter()
         if self._last_decode_end is not None:
             self.stats.max_decode_gap_s = max(
                 self.stats.max_decode_gap_s, now - self._last_decode_end)
@@ -610,11 +635,11 @@ class ServingEngine:
         if rid in self._ttft:
             return
         # a missing admission timestamp would silently report ~0 TTFT
-        # through the old `.get(rid, time.time())` fallback — every path
-        # into the scheduler goes through add_request, so it's a bug
+        # through a `.get(rid, now)` fallback — every path into the
+        # scheduler goes through add_request, so it's a bug
         assert rid in self._t_added, \
             f"request {rid} has no admission timestamp"
-        self._ttft[rid] = time.time() - self._t_added[rid]
+        self._ttft[rid] = time.perf_counter() - self._t_added[rid]
 
     def _emit_new_tokens(self, seq: Sequence) -> None:
         """Stream resolved tokens past the per-request watermark."""
@@ -672,6 +697,7 @@ class _InflightStep:
     #: (replay regenerates them bit-identically).
     entries: list[tuple[Sequence, int, int, int]]
     kind: str            # 'decode' | 'chunk'
+    step: int            # the ``step`` argument of its host spans
 
 
 class PipelinedEngine(ServingEngine):
@@ -721,6 +747,7 @@ class PipelinedEngine(ServingEngine):
             raise ValueError(f"pipeline_depth {config.pipeline_depth} < 1")
         self.depth = config.pipeline_depth
         self._inflight: deque[_InflightStep] = deque()
+        self._n_dispatched = 0   # device programs launched (span ``step``)
         run = self.run_cfg  # parent resolved the kv_dtype override
 
         # `greedy` is static under jit: an all-greedy batch compiles a
@@ -814,8 +841,7 @@ class PipelinedEngine(ServingEngine):
             finished.extend(self._harvest())
         if not self._dispatch() and self._inflight:
             finished.extend(self._harvest())
-        self._sync_sched_stats()
-        return [self._record(seq) for seq in finished]
+        return self._finish_step(finished)
 
     def has_work(self) -> bool:
         return self.scheduler.has_work() or bool(self._inflight)
@@ -827,20 +853,22 @@ class PipelinedEngine(ServingEngine):
         COW copies, budgeted prefill chunks, one decode batch) without
         waiting for any of it.  False when there was nothing to do."""
         dispatched = False
-        while self.scheduler.try_admit() is not None:
-            pass
-        self._run_pending_copies()
-        for seq, n in self.scheduler.plan_prefill(self.prefill_chunk,
-                                                  self.prefill_budget):
-            self._dispatch_chunk(seq, n)
+        for seq, n in self._plan_prefill():
+            with self._dispatch_span("engine.dispatch_chunk"):
+                self._dispatch_chunk(seq, n)
             dispatched = True
-        if self.scheduler.decode_slots():
-            self.scheduler.grow_for_decode()
-            decode = self.scheduler.decode_slots()  # eviction may shrink it
-            if decode:
+        decode = self._plan_decode()
+        if decode:
+            with self._dispatch_span("engine.dispatch_decode"):
                 self._dispatch_decode(decode)
-                dispatched = True
+            dispatched = True
         return dispatched
+
+    def _dispatch_span(self, name: str):
+        """Host span of one program's launch; the harvest of the same
+        program carries the same ``step``."""
+        self._n_dispatched += 1
+        return jax.profiler.TraceAnnotation(name, step=self._n_dispatched)
 
     def _dispatch_chunk(self, seq: Sequence, n: int) -> None:
         """Launch one prompt chunk; the final chunk fuses first-token
@@ -924,7 +952,8 @@ class PipelinedEngine(ServingEngine):
     def _push_inflight(self, toks, entries, kind: str) -> None:
         if hasattr(toks, "copy_to_host_async"):
             toks.copy_to_host_async()  # overlap D2H with the next dispatch
-        self._inflight.append(_InflightStep(toks, entries, kind))
+        self._inflight.append(_InflightStep(toks, entries, kind,
+                                            self._n_dispatched))
         self.stats.inflight_peak = max(self.stats.inflight_peak,
                                        len(self._inflight))
 
@@ -937,15 +966,25 @@ class PipelinedEngine(ServingEngine):
         left (i.e. are ready to record).
         """
         rec = self._inflight.popleft()
-        t0 = time.time()
-        # lint: allow-host-sync — the pipelined engine's ONE intended
-        # device wait: harvesting a step dispatched `depth` steps ago,
-        # and only the (n,) int32 sampled tokens — never full logits
-        # (the decode-sampled contract pins the shape); D2H was started
-        # early by copy_to_host_async at dispatch
-        host = np.asarray(rec.tokens)  # (n,) int32 — never full logits
-        now = time.time()
-        self.stats.harvest_wait_s += now - t0
+        with jax.profiler.TraceAnnotation("engine.harvest_wait",
+                                          step=rec.step):
+            # lint: allow-host-sync — the pipelined engine's ONE intended
+            # device wait: harvesting a step dispatched `depth` steps
+            # ago, and only the (n,) int32 sampled tokens — never full
+            # logits (the decode-sampled contract pins the shape); D2H
+            # was started early by copy_to_host_async at dispatch.  The
+            # wait is a bare block_until_ready, so that the device's
+            # gaps fall under this span and not under np.asarray's own
+            rec.tokens.block_until_ready()
+            host = np.asarray(rec.tokens)  # lint: allow-host-sync
+        with jax.profiler.TraceAnnotation("engine.resolve", step=rec.step):
+            return self._resolve(rec, host)
+
+    def _resolve(self, rec: _InflightStep, host: np.ndarray
+                 ) -> list[Sequence]:
+        """Write a harvested step's tokens into its sequences: EOS
+        rollback of the speculated tail, streaming, finish checks."""
+        now = time.perf_counter()
         if rec.kind == "decode":
             # completion-to-completion stall metric, as in the sync path
             if self._last_decode_end is not None:

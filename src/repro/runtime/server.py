@@ -34,6 +34,8 @@ import asyncio
 import queue
 import threading
 
+import jax
+
 from repro.runtime.engine import GenerationResult, ServingEngine
 
 
@@ -237,7 +239,10 @@ class AsyncServingServer:
     def _drive(self) -> None:
         try:
             while not self._stop.is_set():
-                self._drain_ingress()
+                if not self._ingress.empty():
+                    # only when a request waits: idle polls record nothing
+                    with jax.profiler.TraceAnnotation("server.ingress"):
+                        self._drain_ingress()
                 if not self.engine.has_work():
                     self._stop.wait(self._POLL_S)
                     continue

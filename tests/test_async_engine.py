@@ -313,8 +313,7 @@ def test_pipelined_stats_and_handle(small_lm):
     res = h.result()          # drives step() across dispatch + harvest
     assert h.done and len(res.tokens) == 6
     assert h.ttft_s is not None and h.ttft_s >= 0.0
-    assert eng.stats.inflight_peak >= 1
-    assert eng.stats.harvest_wait_s >= 0.0
+    assert 1 <= eng.stats.inflight_peak <= eng.depth
     assert not eng.has_work() and not eng._inflight
 
 

@@ -196,3 +196,43 @@ def test_request_handle_done_under_concurrent_completion(small_lm):
     res_a = h_a.result()
     assert eng.stats.steps == steps_before, "done handle must not step"
     assert streamed == list(res_a.tokens) and len(res_a.tokens) == 4
+
+
+#: the driver thread's host spans (runtime/server.py, runtime/engine.py)
+HOST_SPANS = ("server.ingress", "engine.plan", "engine.dispatch_chunk",
+              "engine.dispatch_decode", "engine.harvest_wait",
+              "engine.resolve")
+
+
+def test_server_host_spans_in_a_profiler_trace(small_lm, tmp_path):
+    """Under ``jax.profiler.trace`` the driver thread records its six
+    host phases as spans that never nest in one another, with one
+    ``engine.dispatch_decode`` per decode step; the benchmark's trace
+    reader loads them."""
+    from bench import trace as T
+
+    eng = _engine(small_lm)
+    rng = np.random.default_rng(2)
+    reqs = [dict(prompt=rng.integers(0, 128, size=int(n)).tolist(),
+                 max_new_tokens=int(m), temperature=t)
+            for n, m, t in [(9, 5, 0.0), (20, 7, 0.8), (5, 4, 0.0)]]
+
+    async def go():
+        async with AsyncServingServer(eng) as srv:
+            streams = [await srv.submit(**r) for r in reqs]
+            for s in streams:
+                await s.result()
+
+    asyncio.run(go())          # compiles every program, outside the trace
+    steps = eng.stats.steps
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation(T.WINDOW_SPAN):
+            asyncio.run(go())
+    tr = T.load(T.find(str(tmp_path)))
+    spans = sorted((e for e in tr.host if e.name in HOST_SPANS),
+                   key=lambda e: e.start)
+    assert {e.name for e in spans} == set(HOST_SPANS)
+    n_decode = sum(e.name == "engine.dispatch_decode" for e in spans)
+    assert n_decode == eng.stats.steps - steps > 0
+    for a, b in zip(spans, spans[1:]):
+        assert a.end <= b.start, f"{a.name} and {b.name} overlap"

@@ -9,7 +9,8 @@ chip.  These tests compile for a described — not attached — v5e:
   int8 pools);
 * the model's whole prefill-chunk and decode-step programs at
   qwen3-32b's published widths cut to two layers, checking that they
-  fit the chip's 16 GB of HBM;
+  fit the chip's 16 GB of HBM and name their kernel's three passes as
+  a profiler trace shows them;
 * that ``VMEM_BUDGET`` is the scoped VMEM limit the kernels compile
   under.
 
@@ -24,6 +25,7 @@ compiles live in this one file so that one worker holds the library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +35,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import RunConfig, get_arch
 from repro.core.policies import SoftmaxPolicy
-from repro.kernels.common import VMEM_BUDGET
+from repro.kernels.common import PAGED_PASSES, VMEM_BUDGET, pass_name
 from repro.kernels.lut_attention.ops import (lut_attention_paged_decode,
                                              lut_attention_paged_prefill)
 from repro.models import build_model
@@ -132,13 +134,15 @@ def test_paged_prefill_compiles_for_v5e(for_tpu, policy, quantized):
     assert _count_kernels(compiled) == 3
 
 
-@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
-@pytest.mark.parametrize("phase", ["prefill_chunk", "decode"])
-def test_serving_step_fits_one_v5e(for_tpu, phase, kv_dtype):
-    """The engine's step programs at qwen3-32b widths (2 layers, f32
-    weights, the launcher's dtype): the fused kernels are in the program
-    and weights + pool + temporaries fit one chip's HBM."""
-    dev = for_tpu
+#: compiled step programs, by (phase, kv_dtype): each compiles once
+_STEPS = {}
+
+
+def _step_program(dev, phase, kv_dtype):
+    """The engine's step program at qwen3-32b widths (2 layers, f32
+    weights, the launcher's dtype), compiled for the described chip."""
+    if (phase, kv_dtype) in _STEPS:
+        return _STEPS[phase, kv_dtype]
     model = build_model(get_arch("qwen3-32b").with_layers(2))
     run = RunConfig(dtype="float32", attention_backend="naive",
                     softmax_policy=POLICIES["rexp-uint8"],
@@ -157,12 +161,37 @@ def test_serving_step_fits_one_v5e(for_tpu, phase, kv_dtype):
             params, _sds(dev, (1, CHUNK), jnp.int32), pools,
             _sds(dev, (1, MP), jnp.int32), _sds(dev, (1,), jnp.int32),
             _sds(dev, (1,), jnp.int32), run)
-    compiled = lowered.compile()
+    _STEPS[phase, kv_dtype] = lowered.compile()
+    return _STEPS[phase, kv_dtype]
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("phase", ["prefill_chunk", "decode"])
+def test_serving_step_fits_one_v5e(for_tpu, phase, kv_dtype):
+    """The fused kernels are in the step program and weights + pool +
+    temporaries fit one chip's HBM."""
+    compiled = _step_program(for_tpu, phase, kv_dtype)
     assert _count_kernels(compiled) == 3  # one layer body, scanned
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("phase", ["prefill_chunk", "decode"])
+def test_serving_step_names_its_kernel_passes(for_tpu, phase, kv_dtype):
+    """Each of the kernel's three passes is an instruction of the
+    compiled step program under the pass's own name, the head of the
+    label a TPU profiler trace gives the op (``%paged_decode_sum.6 =
+    ...``)."""
+    text = _step_program(for_tpu, phase, kv_dtype).as_text()
+    kernel = ("paged_decode" if phase == "decode" else "paged_prefill") + (
+        "_int8" if kv_dtype == "int8" else "")
+    for p in PAGED_PASSES:
+        name = pass_name(kernel, p)
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call",
+                         text), name
 
 
 @pytest.mark.parametrize("rows,fits", [(7 * 1024, True), (9 * 1024, False)],
