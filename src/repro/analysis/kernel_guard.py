@@ -12,7 +12,10 @@ declaration automatically.  From those declarations the guard derives:
 (a) **VMEM working sets** — per pass, block bytes of every operand,
     double-buffered when the index map varies along the innermost
     (sequential) grid axis, single-copy when resident (accumulators,
-    LUTs, the q block); checked against ``kernels/common.py``'s
+    LUTs, the q block), plus the VMEM scratch a pass declares — an
+    operand whose blocks the kernel copies itself (a ``PageCopy`` from
+    HBM) holds no VMEM of its own, its double buffer is that scratch;
+    checked against ``kernels/common.py``'s
     ``VMEM_BUDGET`` with ``VMEM_GUARD_HEADROOM`` at every declared
     dispatch geometry.
 
@@ -26,7 +29,8 @@ declaration automatically.  From those declarations the guard derives:
 (c) **Grid / index-map coverage** — enumerating the declared grid, every
     output block is written exactly once (index invariant along the
     accumulation axis, bijective over the outer axes, full coverage),
-    and block-table-driven input indices stay inside the pool for the
+    and block-table-driven input indices (a BlockSpec's, or each of a
+    ``PageCopy``'s copies of a grid step) stay inside the pool for the
     whole declared table domain; the shard_map kernels' page-id clamp
     helpers are probed at slab boundaries (mask / drop semantics).
 
@@ -55,7 +59,8 @@ import numpy as np
 
 from repro.core import lut_builder
 from repro.core.precision import SIGMA_ACC_LIMIT, F32_EXACT_LIMIT, INT32_LIMIT
-from repro.kernels.common import VMEM_BUDGET, VMEM_GUARD_HEADROOM, cdiv
+from repro.kernels.common import (VMEM_BUDGET, VMEM_GUARD_HEADROOM, PageCopy,
+                                  cdiv)
 
 REPORT_VERSION = 1
 REPORT_NAME = "ANALYSIS_kernels.json"
@@ -90,6 +95,16 @@ class Operand:
 
 
 @dataclasses.dataclass(frozen=True)
+class Scratch:
+    """One ``scratch_shapes`` entry of a pass (``pltpu.VMEM`` / ``SMEM``
+    / ``SemaphoreType``): only VMEM scratch counts toward the working
+    set, at its declared shape (a double buffer declares its 2)."""
+
+    name: str
+    ref: object               # the launcher's scratch entry: .shape/.dtype
+
+
+@dataclasses.dataclass(frozen=True)
 class PassSpec:
     """One pallas_call of a multi-pass kernel."""
 
@@ -100,6 +115,7 @@ class PassSpec:
     scalar_prefetch: tuple = ()   # synthetic np arrays fed to index maps
     sigma_acc: bool = False       # accumulates the integer Σ
     acc_dtype: str = "float32"    # accumulator dtype (output refs)
+    scratch: tuple = ()           # Scratch entries, in launch order
     notes: str = ""
 
 
@@ -194,6 +210,12 @@ def _block_bytes(op: Operand) -> int:
     return math.prod(op.spec.block_shape) * _DTYPE_BYTES[op.dtype]
 
 
+def _copied(op: Operand) -> bool:
+    """The kernel copies this operand's blocks itself (a ``PageCopy``):
+    it stays in HBM, and its VMEM is the pass's declared scratch."""
+    return isinstance(op.spec, PageCopy)
+
+
 def _eval_index(op: Operand, ps: PassSpec, coords) -> tuple:
     out = op.spec.index_map(*coords, *ps.scalar_prefetch)
     return tuple(int(x) for x in out)
@@ -201,21 +223,33 @@ def _eval_index(op: Operand, ps: PassSpec, coords) -> tuple:
 
 def _varies_innermost(op: Operand, ps: PassSpec) -> bool:
     """Does the block index change along the innermost (sequential) axis?"""
+    if _copied(op):
+        return False
     outer = (0,) * (len(ps.grid) - 1)
     idxs = {_eval_index(op, ps, (*outer, k)) for k in range(ps.grid[-1])}
     return len(idxs) > 1
 
 
+def scratch_bytes(ps: PassSpec) -> dict[str, int]:
+    """VMEM bytes of each VMEM scratch entry of a pass."""
+    return {sc.name: math.prod(sc.ref.shape) * np.dtype(sc.ref.dtype).itemsize
+            for sc in ps.scratch if str(sc.ref.memory_space) == "vmem"}
+
+
 def pass_working_set(ps: PassSpec) -> dict:
     """Derived VMEM bytes of one pass: streamed operands double-buffered,
     resident ones (accumulators, LUTs, blocks constant along the
-    sequential axis) single-copy."""
+    sequential axis) single-copy, operands the kernel copies itself
+    none (they stay in HBM), plus the declared VMEM scratch."""
     per: dict[str, int] = {}
     for op in (*ps.inputs, *ps.outputs):
+        if _copied(op):
+            continue
         mult = 2 if _varies_innermost(op, ps) else 1
         # the same operand may appear under one name in several roles
         # (m as input and output); count the larger footprint once
         per[op.name] = max(per.get(op.name, 0), _block_bytes(op) * mult)
+    per.update(scratch_bytes(ps))
     per["total"] = sum(v for k, v in per.items() if k != "total")
     return per
 
@@ -283,7 +317,9 @@ def _input_range_violations(kname: str, ps: PassSpec) -> list[str]:
             out.append(f"{kname}/{ps.name}: input {op.name!r} is "
                        f"table-indexed but declares no index_domain")
             continue
-        for coords in itertools.product(*map(range, ps.grid)):
+        # a kernel's own copies: every one of the step's ``count`` blocks
+        copies = (range(op.spec.count),) if _copied(op) else ()
+        for coords in itertools.product(*map(range, ps.grid), *copies):
             idx = _eval_index(op, ps, coords)
             if len(idx) != len(blocks) or any(
                     not 0 <= c < nb for c, nb in zip(idx, blocks)):

@@ -22,6 +22,9 @@ All three produce bit-identical int32 results (property-tested).
 
 from __future__ import annotations
 
+import dataclasses
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 
@@ -59,6 +62,26 @@ def pass_name(kernel: str, pass_: str) -> str:
     """Device name of one pass: ``<KernelSpec.name>_<PassSpec.name>``,
     e.g. ``paged_decode_int8_rowmax``."""
     return f"{kernel}_{pass_}"
+
+
+@dataclasses.dataclass(frozen=True)
+class PageCopy:
+    """The DMAs a kernel issues itself from an HBM operand
+    (``memory_space=pltpu.HBM``): ``count`` blocks of ``block_shape`` per
+    grid step, the ``j``-th at block index ``index_map(*grid_point, j,
+    *scalar_prefetch)``.  The block's last two dims are whole, so a copy
+    reads ``src.at[index[:-2]]``.  The kernel issues its copies from this
+    declaration, and ``analysis.kernel_guard`` proves their indices in
+    range from the same one."""
+
+    block_shape: tuple
+    count: int
+    index_map: Callable
+
+    def source(self, src, *coords):
+        """The ref slice the copy at ``coords`` (grid point, ``j``,
+        scalar-prefetch refs) reads."""
+        return src.at[tuple(self.index_map(*coords)[:-2])]
 
 
 #: per-kernel VMEM working-set budget (bytes): the scoped VMEM limit the

@@ -658,10 +658,11 @@ def lut_attention_paged_decode(
     """Decode attention straight off the paged KV pool.
 
     Dispatches per :func:`resolve_paged_backend`: on TPU the fused
-    Pallas kernel reads K/V through the per-slot block tables (one page
-    per grid step — no contiguous (B, KVH, Lk, D) gather, no logits
-    tensor); elsewhere the dense reference gathers the block-table view and
-    reuses :func:`lut_attention_decode_varlen`.  Per-key numerics are
+    Pallas kernel reads K/V through the per-slot block tables (a block
+    of pages per grid step, blocks past ``kv_lens`` skipped — no
+    contiguous (B, KVH, Lk, D) gather, no logits tensor); elsewhere the
+    dense reference gathers the block-table view and reuses
+    :func:`lut_attention_decode_varlen`.  Per-key numerics are
     identical across paths (the parity suite pins this), so serving
     output does not depend on where a slot decodes.
 

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import RunConfig
+from repro.kernels.lut_attention.paged_decode import decode_block_pages
 from repro.models.model_zoo import Model
 from repro.models import transformer as TF
 from repro.runtime.paged_cache import (PagedCacheConfig, decode_view,
@@ -199,6 +200,12 @@ class EngineStats:
     speculative_wasted: int = 0  # dispatched slot-steps rolled back at EOS
     inflight_peak: int = 0       # max device steps simultaneously in flight
     queue_depth_peak: int = 0    # max waiting-queue depth observed
+    # the decode kernel's block sweep (``decode_block_pages`` pages a
+    # block): the blocks its grid walks, slots × blocks per decode step,
+    # and those holding a key, Σ over slots of ceil(kv_len / block keys);
+    # the rest the kernel skips
+    decode_kv_blocks: int = 0
+    decode_kv_blocks_live: int = 0
 
     @property
     def tokens(self) -> int:
@@ -589,10 +596,20 @@ class ServingEngine:
         self._emit_new_tokens(seq)
         return done
 
+    def _decode_view(self, running: dict[int, Sequence]):
+        """The decode step's device view, its kernel blocks counted."""
+        host = decode_view(running, self.n_slots, self.cache)
+        mp = self.cache.max_pages_per_seq
+        pages = decode_block_pages(self.cache.page_size, mp)
+        kv_lens = host.lengths.astype(np.int64) + 1  # incl. the new token
+        self.stats.decode_kv_blocks += len(kv_lens) * (mp // pages)
+        self.stats.decode_kv_blocks_live += int(np.sum(
+            -(-kv_lens // (pages * self.cache.page_size))))
+        return view_arrays(host, self.mesh)
+
     def _decode_step(self, running: dict[int, Sequence]) -> list[Sequence]:
         """One batched decode step over the running slots."""
-        view = view_arrays(decode_view(running, self.n_slots, self.cache),
-                           self.mesh)
+        view = self._decode_view(running)
         with self._mesh_ctx():
             logits, self.pools = self._decode_fn(
                 self.params, view.tokens, self.pools, view.block_tables,
@@ -917,8 +934,7 @@ class PipelinedEngine(ServingEngine):
         # the view is built BEFORE the speculative append: lengths must
         # count only tokens whose K/V the pool already holds (plus the
         # input token, written by this step) — exactly the sync math
-        view = view_arrays(decode_view(running, self.n_slots, self.cache),
-                           self.mesh)
+        view = self._decode_view(running)
         seeds = [0] * self.n_slots
         positions = [0] * self.n_slots
         temps = [0.0] * self.n_slots

@@ -61,8 +61,9 @@ def pool_shape(n_pages: int, page_size: int, n_kv_heads: int,
     Single source of truth for the device pool shape: the leading axis
     is the physical page id (what a block-table entry indexes), and
     within a page each KV head's ``(page_size, Dh)`` tokens are
-    contiguous — the unit the paged kernels DMA per grid step, and a
-    block whose last two dimensions are whole array dimensions, which
+    contiguous — the unit the paged kernels DMA (prefill one a grid
+    step, decode a block of them), and a block whose last two dimensions
+    are whole array dimensions, which
     Mosaic's TPU tiling rule requires (a token-major page would put a
     size-1 KV-head block inside the tiled dimensions).
 

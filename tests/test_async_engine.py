@@ -8,6 +8,8 @@ workload — altered scheduling, fused sampling and late harvests must
 all be unobservable in the output stream.
 """
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -315,6 +317,37 @@ def test_pipelined_stats_and_handle(small_lm):
     assert h.ttft_s is not None and h.ttft_s >= 0.0
     assert 1 <= eng.stats.inflight_peak <= eng.depth
     assert not eng.has_work() and not eng._inflight
+
+
+@pytest.mark.parametrize("engine_cls", [ServingEngine, PipelinedEngine])
+def test_decode_kv_block_counters(small_lm, engine_cls):
+    """EngineStats counts the decode kernel's blocks from each decode
+    view's lengths: slots × blocks a step, and ceil(kv_len / block keys)
+    a slot that holds keys — the share of a known set of lengths, by
+    hand; over a run, every step adds the whole grid."""
+    model, params = small_lm
+    # 40-page tables of 8-key pages: blocks of 10 pages (80 keys), 4 a slot
+    cache = PagedCacheConfig(n_pages=64, page_size=8, max_pages_per_seq=40)
+    eng = engine_cls(model, params, _run_cfg("exact"),
+                     EngineConfig(n_slots=4, cache=cache))
+    held = {0: 80, 2: 81, 3: 161}     # tokens incl. the one entering
+    running = {slot: types.SimpleNamespace(
+        pages=list(range(1, -(-n // 8) + 1)), total_tokens=n, generated=[5])
+        for slot, n in held.items()}
+    for _ in range(2):
+        eng._decode_view(running)
+    # kv_lens 80, 1 (the empty slot), 81, 161 → 1 + 1 + 2 + 3 of 4 × 4
+    assert eng.stats.decode_kv_blocks == 2 * 16
+    assert eng.stats.decode_kv_blocks_live == 2 * 7
+    assert (eng.stats.decode_kv_blocks_live
+            / eng.stats.decode_kv_blocks) == 7 / 16
+
+    fresh = engine_cls(model, params, _run_cfg("exact"),
+                       EngineConfig(n_slots=4, cache=cache))
+    fresh.run(_mixed_requests(np.random.default_rng(10), n=4))
+    assert fresh.stats.decode_kv_blocks == fresh.stats.steps * 16
+    assert fresh.stats.steps <= fresh.stats.decode_kv_blocks_live <= (
+        fresh.stats.decode_kv_blocks)
 
 
 def test_pipelined_no_rejit_across_steps(small_lm):

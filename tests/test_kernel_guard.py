@@ -8,6 +8,8 @@ failure modes the guard exists for.
 """
 
 import dataclasses
+import itertools
+import math
 
 import numpy as np
 import pytest
@@ -192,9 +194,11 @@ def test_shrunk_budget_flips_vmem_contract():
 
 def _streamed_bytes(ps):
     """Double-buffered bytes of the operands that stream along the
-    innermost (page) axis — the pool traffic the quantized pools halve."""
+    innermost (page) axis, and the VMEM scratch a kernel copies its pages
+    into — the pool traffic the quantized pools halve."""
     return sum(2 * kg._block_bytes(op) for op in ps.inputs
-               if kg._varies_innermost(op, ps))
+               if kg._varies_innermost(op, ps)) + sum(
+                   kg.scratch_bytes(ps).values())
 
 
 @pytest.mark.parametrize("base", ["paged_decode", "paged_prefill"])
@@ -202,10 +206,11 @@ def _streamed_bytes(ps):
 def test_int8_streamed_vmem_at_most_055x(base, gname):
     """int8 pages + f32 scales stream ≤ 0.55× the f32 pages' bytes.
 
-    Per page block the ratio is (ps·dh·1 + ps·4) / (ps·dh·4) =
-    (dh + 4) / (4·dh) — ≤ 0.32 for every shipped head dim, asserted at
-    the looser 0.55 criterion so a future scale-granularity change has
-    headroom without losing the headline.
+    Per page the ratio is (ps·dh·1 + s·4) / (ps·dh·4), with s = ps
+    scales (prefill: the head's) or KVH·ps (decode: every head's row) —
+    ≤ 0.32 for the shipped head dims and 0.5 at the test geometry's
+    dh 16, asserted at the looser 0.55 criterion so a future
+    scale-granularity change has headroom without losing the headline.
     """
     reg = kg.kernel_registry(kg.GEOMETRIES[gname])
     f32 = {p.name: p for p in reg[base].passes}
@@ -231,6 +236,61 @@ def test_int8_clean_and_scale_less_spec_flips_contract(base):
         for ps in spec.passes))
     v, _ = kg.check_kernel(stripped)
     assert any("no float32 scale operand" in x for x in v)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("gname", sorted(kg.GEOMETRIES))
+def test_paged_decode_declares_its_scratch_and_it_fits(gname, quantized):
+    """The decode kernel copies its blocks itself: each pass declares a
+    double-buffered (2, P, ps, Dh) page scratch per stream (K; K and V
+    in σ·V) with two DMA semaphores, and the sweep's SMEM state.  The
+    pool operands count no VMEM of their own, the scratch counts in
+    full, and the whole fits the budget at every geometry."""
+    from repro.kernels.lut_attention.paged_decode import decode_block_pages
+    geom = kg.GEOMETRIES[gname]
+    name = "paged_decode_int8" if quantized else "paged_decode"
+    spec = kg.kernel_registry(geom)[name]
+    pages = decode_block_pages(geom["page_size"], geom["mp"])
+    buf = (2, pages, geom["page_size"], geom["dh"])
+    page_bytes = 1 if quantized else 4
+    for ps in spec.passes:
+        streams = ("k", "v") if ps.name == "weight" else ("k",)
+        shapes = {sc.name: (tuple(sc.ref.shape), str(sc.ref.memory_space))
+                  for sc in ps.scratch}
+        want = {"sweep_state": ((2,), "smem")}
+        for st in streams:
+            want[f"{st}_buf"] = (buf, "vmem")
+            want[f"{st}_sems"] = ((2,), "semaphore_mem")
+        assert shapes == want, (ps.name, shapes)
+        ws = kg.pass_working_set(ps)
+        assert not {"k_pages", "v_pages"} & set(ws)
+        for st in streams:
+            assert ws[f"{st}_buf"] == math.prod(buf) * page_bytes
+        assert ws["total"] <= kg.vmem_limit()
+    assert not kg.check_kernel(spec)[0]
+
+
+def test_paged_decode_copy_probe_covers_the_page_domain():
+    """The decode kernel's own page copies are range-checked over every
+    grid point and every page of a block; the probe table reaches both
+    ends of the page-id domain, and a copy that reads one page past it
+    flips the contract."""
+    geom = kg.GEOMETRIES["serve-default"]
+    spec = kg.kernel_registry(geom)["paged_decode"]
+    weight = next(p for p in spec.passes if p.name == "weight")
+    k = next(op for op in weight.inputs if op.name == "k_pages")
+    ids = {kg._eval_index(k, weight, (*pt, j))[0]
+           for pt in itertools.product(*map(range, weight.grid))
+           for j in range(k.spec.count)}
+    assert ids == {0, geom["n_pages"] - 1}
+    assert not kg._input_range_violations(spec.name, weight)
+    off = dataclasses.replace(k.spec, index_map=lambda *a: (
+        k.spec.index_map(*a)[0] + 1, *k.spec.index_map(*a)[1:]))
+    bad = dataclasses.replace(weight, inputs=tuple(
+        dataclasses.replace(op, spec=off) if op is k else op
+        for op in weight.inputs))
+    v = kg._input_range_violations(spec.name, bad)
+    assert any("'k_pages'" in x and "outside grid" in x for x in v)
 
 
 # ---------------------------------------------------------------------------

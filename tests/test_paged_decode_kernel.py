@@ -9,7 +9,14 @@ V-contraction accumulates page-chunked instead of row-at-once, so the
 comparisons pin a roundoff-level tolerance (2e-6, ~16 ulp at the output
 scale) rather than bit equality — the same convention the blocked/pallas
 full-attention kernels use against their naive oracle.
+
+The kernel sweeps a block table in blocks of ``decode_block_pages``
+pages and skips blocks past a slot's ``kv_lens``; the blocked-sweep
+cases pin its geometries (P > 1, P = 1, a table width 128 / page_size
+does not divide) and the lengths at a block's edges.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +28,8 @@ from repro.kernels.lut_attention.ops import (_tables_for, gather_pages,
                                              lut_attention_decode_varlen,
                                              lut_attention_paged_decode,
                                              resolve_paged_backend)
-from repro.kernels.lut_attention.paged_decode import paged_decode_attention
+from repro.kernels.lut_attention.paged_decode import (decode_block_pages,
+                                                      paged_decode_attention)
 
 POLICIES = strategies.make_policies()
 
@@ -87,22 +95,154 @@ def test_kernel_ragged_lengths_edges(rng, kv_lens):
 
 def test_kernel_ignores_junk_pages(rng):
     """Pages outside a slot's block table — including the null page —
-    must not influence its output: poison them and compare."""
+    must not influence its output: poison them and compare.  Blocks past
+    a slot's ``kv_lens`` point at NaN pages: the kernel never reads them,
+    so the output stays bitwise equal."""
     pol = POLICIES["lut2d"]
-    q, kp, vp, bt, kls = _paged_problem(rng, kv_lens=(9, 13, 5))
+    ps, mp = 16, 24                  # 3 blocks of 8 pages (128 keys)
+    q, kp, vp, bt, kls = _paged_problem(rng, ps=ps, mp=mp,
+                                        kv_lens=(9, 140, 5))
     ref = paged_decode_attention(q, kp, vp, bt, kls, _tables_for(pol),
                                  method=pol.impl, index_mode=pol.index_mode)
     owned = set()
-    bt_np = np.asarray(bt)
+    bt_np = np.asarray(bt).copy()
     for i, kl in enumerate(np.asarray(kls)):
-        owned.update(bt_np[i, :-(-int(kl) // kp.shape[2])])
+        owned.update(bt_np[i, :-(-int(kl) // ps)])
     junk = [p for p in range(kp.shape[0]) if p not in owned]
     kp2 = kp.at[jnp.asarray(junk)].set(1e6)
     vp2 = vp.at[jnp.asarray(junk)].set(-1e6)
-    # also poison the masked tail of each slot's LAST page
-    out = paged_decode_attention(q, kp2, vp2, bt, kls, _tables_for(pol),
-                                 method=pol.impl, index_mode=pol.index_mode)
+    # every page of a dead block becomes a NaN page of its own
+    block = decode_block_pages(ps, mp)
+    n_pages = kp.shape[0]
+    dead = [(i, c) for i, kl in enumerate(np.asarray(kls))
+            for c in range(-(-int(kl) // (block * ps)) * block, mp)]
+    assert dead
+    for n, (i, c) in enumerate(dead):
+        bt_np[i, c] = n_pages + n
+    nan = jnp.full((len(dead),) + kp.shape[1:], jnp.nan, kp.dtype)
+    out = paged_decode_attention(
+        q, jnp.concatenate([kp2, nan]), jnp.concatenate([vp2, nan]),
+        jnp.asarray(bt_np), kls, _tables_for(pol), method=pol.impl,
+        index_mode=pol.index_mode)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# The blocked sweep: geometries from the shapes, block edges, dead blocks
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("page_size,mp,pages", [
+    (16, 64, 8),     # qwen3-32b-tp8 cell: 8 pages, 128 keys a block
+    (16, 160, 8),    # internlm2-20b-tp8 cell
+    (16, 12, 6),     # 128 / page_size does not divide the table width
+    (16, 13, 1),     # a prime width: one page a block
+    (4, 5, 5),       # the whole table in one block
+    (256, 4, 1),     # a page wider than a lane row
+])
+def test_block_pages_come_from_the_shapes(page_size, mp, pages):
+    assert decode_block_pages(page_size, mp) == pages
+
+
+#: (page_size, mp) of the blocked-sweep cases: 3 blocks of 8 pages;
+#: 2 blocks of 6 (128 / 16 does not divide 12); 13 blocks of one page
+SWEEPS = {"p8": (16, 24), "p6": (16, 12), "p1": (16, 13)}
+
+
+def _edge_lens(ps, mp):
+    """Lengths at a block's edges, and 1 (what an empty slot sends)."""
+    keys = decode_block_pages(ps, mp) * ps
+    return (keys - 1, keys, keys + 1, 1)
+
+
+@pytest.mark.parametrize("sweep,g", [("p8", 8), ("p8", 6), ("p6", 6),
+                                     ("p1", 8)])
+@pytest.mark.parametrize("impl", sorted(POLICIES))
+def test_blocked_sweep_matches_dense_at_block_edges(rng, impl, sweep, g):
+    """Every policy, the cells' GQA ratios (G = 8 and 6 on one KV head)
+    and each block geometry: lengths one short of a block, exactly a
+    block, one key into the next, and a single key all match the dense
+    reference."""
+    ps, mp = SWEEPS[sweep]
+    pol = POLICIES[impl]
+    q, kp, vp, bt, kls = _paged_problem(rng, b=4, kvh=1, g=g, ps=ps, mp=mp,
+                                        kv_lens=_edge_lens(ps, mp))
+    out = paged_decode_attention(q, kp, vp, bt, kls, _tables_for(pol),
+                                 method=pol.impl, index_mode=pol.index_mode)
+    ref = _dense_ref(q, kp, vp, bt, kls, pol)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
+
+
+@pytest.mark.parametrize("impl", sorted(POLICIES))
+def test_blocked_sweep_int8_pool_matches_dense(rng, impl):
+    """The int8 pool on the blocked sweep (P = 8, G = 6): its scale rows
+    travel by block beside the pages."""
+    from repro.core.quantization import quantize_rows
+    ps, mp = SWEEPS["p8"]
+    pol = POLICIES[impl]
+    q, kp, vp, bt, kls = _paged_problem(rng, b=4, kvh=1, g=6, ps=ps, mp=mp,
+                                        kv_lens=_edge_lens(ps, mp))
+    pools = []
+    for x in (kp, vp):
+        xq, sc = quantize_rows(x)          # (n, KVH, ps, Dh), (n, KVH, ps)
+        pools.append((xq, sc.reshape(x.shape[0], 1, -1)))
+    (kq, ks), (vq, vs) = pools
+    kw = dict(k_scales=ks, v_scales=vs)
+    pal = lut_attention_paged_decode(q, kq, vq, bt, kls, pol,
+                                     backend="pallas", **kw)
+    den = lut_attention_paged_decode(q, kq, vq, bt, kls, pol,
+                                     backend="dense", **kw)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(den), **TOL)
+
+
+def test_blocked_sweep_skips_keyless_slots(rng):
+    """Slots without a key (first, consecutive, last) run no block: their
+    outputs stay the accumulators' init, zero, and the sweep's copies
+    hop over them to the next slot that holds a key."""
+    pol = POLICIES["rexp"]
+    ps, mp = SWEEPS["p8"]
+    lens = (0, 129, 0, 0, 5, 0)
+    q, kp, vp, bt, kls = _paged_problem(rng, b=len(lens), kvh=1, g=8,
+                                        ps=ps, mp=mp, kv_lens=lens)
+    out = np.asarray(paged_decode_attention(
+        q, kp, vp, bt, kls, _tables_for(pol), method=pol.impl,
+        index_mode=pol.index_mode))
+    keyed = np.asarray(lens) > 0
+    assert not out[~keyed].any()
+    ref = _dense_ref(q[keyed], kp, vp, bt[keyed], kls[keyed], pol)
+    np.testing.assert_allclose(out[keyed], np.asarray(ref), **TOL)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_blocked_sweep_copies_are_race_free(rng, quantized):
+    """Under the interpreter that simulates the TPU's DMAs and semaphores
+    (a copy lands at its wait), with its happens-before race detector
+    on, the sweep's copies — the next live block's in flight while a
+    block computes, across keyless slots — race with no read, and the
+    output is bitwise the plain interpreter's."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core.quantization import quantize_rows
+    pol = POLICIES["rexp"]
+    ps, mp = SWEEPS["p8"]
+    q, kp, vp, bt, kls = _paged_problem(rng, b=4, kvh=1, g=8, ps=ps, mp=mp,
+                                        kv_lens=(0, 129, 0, 300))
+    kw = {}
+    if quantized:
+        (kp, ks), (vp, vs) = [
+            (xq, sc.reshape(x.shape[0], 1, -1))
+            for x in (kp, vp) for xq, sc in [quantize_rows(x)]]
+        kw = dict(k_scales=ks, v_scales=vs)
+    run = functools.partial(paged_decode_attention, q, kp, vp, bt, kls,
+                            _tables_for(pol), method=pol.impl,
+                            index_mode=pol.index_mode, **kw)
+    pltpu.reset_tpu_interpret_mode_state()
+    out = run(interpret=pltpu.InterpretParams(detect_races=True))
+    assert interpret_pallas_call.races is not None
+    assert not interpret_pallas_call.races.races_found
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(run(interpret=True)))
 
 
 def test_dispatcher_auto_resolves_dense_on_cpu():

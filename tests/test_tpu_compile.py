@@ -6,7 +6,8 @@ chip.  These tests compile for a described — not attached — v5e:
 
 * both paged kernels through their dispatchers, as the engine calls
   them (decode and prefill; exact, rexp/uint8 and lut2d/uint8; f32 and
-  int8 pools);
+  int8 pools), and the decode kernel's blocked sweep at the benchmark
+  cells' geometries (bf16 and int8 pools);
 * the model's whole prefill-chunk and decode-step programs at
   qwen3-32b's published widths cut to two layers, checking that they
   fit the chip's 16 GB of HBM and name their kernel's three passes as
@@ -38,6 +39,8 @@ from repro.core.policies import SoftmaxPolicy
 from repro.kernels.common import PAGED_PASSES, VMEM_BUDGET, pass_name
 from repro.kernels.lut_attention.ops import (lut_attention_paged_decode,
                                              lut_attention_paged_prefill)
+from repro.kernels.lut_attention.paged_decode import (decode_block_pages,
+                                                      kernel_name)
 from repro.models import build_model
 from repro.runtime.paged_cache import pool_shape, scale_pool_shape
 
@@ -114,6 +117,45 @@ def test_paged_decode_compiles_for_v5e(for_tpu, policy, quantized):
         _sds(dev, (B, MP), jnp.int32), _sds(dev, (B,), jnp.int32),
         **scales).compile()
     assert _count_kernels(compiled) == 3  # row max, Σ, σ·V
+
+
+#: the benchmark cells' decode dispatch (bench/configs): G query heads on
+#: one KV head, n_slots slots, mp pages per sequence, the pool's pages
+CELL_DECODE = {
+    "qwen3-32b-tp8": dict(g=8, slots=32, mp=64, n_pages=6144),
+    "internlm2-20b-tp8": dict(g=6, slots=64, mp=160, n_pages=12288),
+}
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE))
+def test_blocked_paged_decode_compiles_at_cell_geometries(for_tpu, cell,
+                                                          pool):
+    """The decode kernel's blocked sweep (8 pages, 128 keys a grid step,
+    in both cells) compiles for v5e at the cells' geometries, and its
+    three passes are ops under their own names."""
+    dev, c = for_tpu, CELL_DECODE[cell]
+    assert decode_block_pages(PS, c["mp"]) == 8
+    quantized = pool == "int8"
+    pages = _sds(dev, pool_shape(c["n_pages"], PS, 1, DH),
+                 jnp.int8 if quantized else jnp.bfloat16)
+    sc = _sds(dev, scale_pool_shape(c["n_pages"], PS, 1), jnp.float32)
+    scales = dict(k_scales=sc, v_scales=sc) if quantized else {}
+    pol = POLICIES["rexp-uint8"]
+
+    def step(q, kp, vp, bt, kl, **sc):
+        return lut_attention_paged_decode(q, kp, vp, bt, kl, pol,
+                                          backend="pallas", **sc)
+
+    text = jax.jit(step).lower(
+        _sds(dev, (c["slots"], c["g"], 1, DH), jnp.float32), pages, pages,
+        _sds(dev, (c["slots"], c["mp"]), jnp.int32),
+        _sds(dev, (c["slots"],), jnp.int32), **scales).compile().as_text()
+    kernel = kernel_name(quantized)
+    for p in PAGED_PASSES:
+        name = pass_name(kernel, p)
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*tpu_custom_call",
+                         text), name
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
