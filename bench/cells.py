@@ -1,19 +1,24 @@
-"""Cells, configurations, mixes and metric readers, found by name.
+"""Cells, configurations, architectures, mixes and metric readers, found
+by name.
 
 ``BENCHMARK.json`` names every cell.  A cell's configuration is the file
-its ``configs`` entry gives, its traffic mix is ``bench/traffic/<mix>.json``,
-its correctness limit ``bench/checks/<cell>.json``, and each per-layer
-metric is read by ``bench/metrics/<metric>.py``.  Adding a cell is adding
-files and one ``workloads`` entry.
+its ``configs`` entry gives, and its ``bench_arch`` key names the
+architecture module ``bench/arch/<bench_arch>.py`` (see :func:`arch`).
+Its traffic mix is ``bench/traffic/<mix>.json``, its correctness limit
+``bench/checks/<cell>.json``, and each per-layer metric is read by
+``bench/metrics/<metric>.py``.  Adding a cell, of a new architecture too,
+is adding files and one ``workloads`` entry.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
 import pathlib
+import types
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -24,6 +29,7 @@ class Cell:
     name: str
     chips: int
     cfg: dict
+    arch: types.ModuleType
     mix: dict
     check: dict
     end_to_end: list[dict]
@@ -54,21 +60,70 @@ def cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     cfg = _load(root / conf["file"])
     mix = _load(root / "bench" / "traffic" / f"{w['traffic']}.json")
     check = _load(root / "bench" / "checks" / f"{name}.json")
-    return Cell(name=name, chips=w["chips"], cfg=cfg, mix=mix, check=check,
+    return Cell(name=name, chips=w["chips"], cfg=cfg,
+                arch=arch(cfg["bench_arch"], root), mix=mix, check=check,
                 end_to_end=[m for m in bench["end_to_end"]
                             if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"]
                            if _applies(m, name)])
 
 
-def reader(metric: str, root: pathlib.Path = ROOT):
-    """The ``read(readings) -> float | None`` of a per-layer metric."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
+def _module(path: pathlib.Path, prefix: str) -> types.ModuleType:
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, root: pathlib.Path = ROOT):
+    """The ``read(readings) -> float | None`` of a per-layer metric."""
+    return _module(root / "bench" / "metrics" / f"{metric}.py",
+                   "bench_metric_").read
+
+
+@functools.cache
+def _arch_module(path: pathlib.Path) -> types.ModuleType:
+    # once per file, so that its jitted reference layers compile once
+    return _module(path, "bench_arch_")
+
+
+def arch(name: str, root: pathlib.Path = ROOT) -> types.ModuleType:
+    """The architecture module ``bench/arch/<name>.py``.  It imports
+    nothing of the program, and gives, for a configuration ``cfg``:
+
+    - ``dims(cfg)``: the widths one chip runs, with at least ``vocab``
+      and ``norm_eps``;
+    - ``layer_kinds(cfg)``: one kind name per layer, in order;
+    - ``leaves(cfg)``: ``{"top": {...}, "layers": {kind: {...}}}``, each
+      leaf ``name: (shape, init std or ("const", value))``; ``top`` holds
+      ``embed``, ``final_norm`` and ``head``;
+    - ``reference_layer(kind, x, w, dims, softmax, control)``: one layer
+      of that kind over a whole (T, D) float32 sequence, ``dims`` as
+      sorted items (``bench.reference``);
+    - ``decode_attention(kv_len, dims)``, ``prefill_attention(q_start, n,
+      dims)``: (FLOPs, bytes) of the attention over every layer, and
+      ``matmul_flops_per_token(dims)``, ``head_flops(dims)``
+      (``bench.work``);
+    - ``rehearse(cfg)``: ``cfg`` with its own widths cut for a CPU
+      rehearsal (:func:`rehearsal`).
+
+    Its program half, ``bench/arch/<name>_program.py`` (:func:`arch_program`),
+    gives ``arch_config(cfg, dims)`` and ``tree(weights)``
+    (``bench.program``)."""
+    path = root / "bench" / "arch" / f"{name}.py"
+    if name.endswith("_program") or not path.is_file():
+        have = sorted(p.stem for p in path.parent.glob("*.py")
+                      if not p.stem.endswith("_program"))
+        raise KeyError(f"no architecture module {name!r} in bench/arch; "
+                       f"have {have}")
+    return _arch_module(path)
+
+
+def arch_program(mod: types.ModuleType) -> types.ModuleType:
+    """The program half of architecture module ``mod``."""
+    path = pathlib.Path(mod.__file__)
+    return _arch_module(path.with_name(f"{path.stem}_program.py"))
 
 
 #: a CPU rehearsal divides every length by this
@@ -81,16 +136,15 @@ def rehearsal(c: Cell) -> Cell:
     """The same cell at a tiny width and length, for the CPU: every path
     and control flow of the chip run, none of its sizes."""
     cfg = json.loads(json.dumps(c.cfg))
-    tp = cfg["tensor_parallel"]
+    cfg.update(vocab_size=512, num_hidden_layers=REHEARSAL_LAYERS)
+    cfg = c.arch.rehearse(cfg)
     # the cell's own init with the same total branch weight over fewer
     # layers: the layers' branch outputs add in quadrature, so each
     # layer's gains grow by sqrt(layers in the cell / layers here)
     init = cfg["assumed"]["init"]
-    grow = math.sqrt(c.cfg["num_hidden_layers"] / REHEARSAL_LAYERS)
+    grow = math.sqrt(c.cfg["num_hidden_layers"] / cfg["num_hidden_layers"])
     init.update(attn_gain=init["attn_gain"] * grow,
                 mlp_gain=init["mlp_gain"] * grow)
-    cfg.update(hidden_size=64, head_dim=16, intermediate_size=64 * tp,
-               vocab_size=512, num_hidden_layers=REHEARSAL_LAYERS)
     s = cfg["serving"]
     # float32 here, so that a sound rehearsal matches the reference to
     # roundoff; at this size the bfloat16 program's rounding and the fp8

@@ -23,10 +23,11 @@ import jax
 import numpy as np
 
 from bench import cells as C
-from bench import reference, traffic, work, weights as W
+from bench import reference, traffic, work
 
 COUNTERS = ("steps", "decode_tokens", "prompt_tokens", "prefill_steps",
-            "prefix_hit_tokens", "preemptions", "prefills")
+            "prefix_hit_tokens", "preemptions", "prefills",
+            "decode_kv_blocks", "decode_kv_blocks_live")
 
 
 @dataclasses.dataclass
@@ -58,6 +59,12 @@ class Readings:
     chunks: list[tuple]              # (t, q_start, n) per prefill chunk
     trace: object = None             # bench.trace.Trace of the window
     peak: dict | None = None         # bench/peaks.json row of the device
+
+    @property
+    def arch(self):
+        """The configuration's architecture module, which counts the work
+        (``bench.work``)."""
+        return self.cell.arch
 
     def in_window(self, t: float) -> bool:
         return self.t_open <= t < self.t_close
@@ -264,7 +271,8 @@ def sample(r: Readings, seed: int) -> list[Record]:
     return [longest] + rest[:r.cell.mix["check"]["requests"] - 1]
 
 
-def logit_gaps(cfg: dict, seed: int, recs: list[Record], control: bool = False
+def logit_gaps(cell: C.Cell, seed: int, recs: list[Record],
+               control: bool = False
                ) -> tuple[list[np.ndarray], list[np.ndarray] | None]:
     """Per request and served token, the gap by which the token's
     reference logit lies below the reference's best at that position.
@@ -279,7 +287,7 @@ def logit_gaps(cfg: dict, seed: int, recs: list[Record], control: bool = False
         served.append(toks)
     if not recs:
         return [], ([] if control else None)
-    refs = reference.forward(cfg, seed, seqs, rows)
+    refs = reference.forward(cell.arch, cell.cfg, seed, seqs, rows)
 
     def gaps(picks):
         return [ref.max(-1) - ref[np.arange(len(p)), p]
@@ -287,7 +295,8 @@ def logit_gaps(cfg: dict, seed: int, recs: list[Record], control: bool = False
 
     if not control:
         return gaps(served), None
-    low = reference.forward(cfg, seed, seqs, rows, control=True)
+    low = reference.forward(cell.arch, cell.cfg, seed, seqs, rows,
+                            control=True)
     return gaps(served), gaps([c.argmax(-1) for c in low])
 
 
@@ -334,14 +343,14 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     device = device_info(cell.chips, require_tpu=not rehearse)
     peak = None if rehearse else work.peaks(device["kind"])
     cfg, mix = cell.cfg, cell.mix
-    dims = W.model_dims(cfg)
+    dims = cell.arch.dims(cfg)
     clock = CompileClock()
     if not rehearse and program.backends() != ("pallas", "pallas"):
         raise SystemExit(f"paged attention resolves to {program.backends()}, "
                          "not the compiled kernels")
 
-    tree = program.params(cfg, seed)
-    eng = program.engine(cfg, mix, tree)
+    tree = program.params(cell.arch, cfg, seed)
+    eng = program.engine(cell.arch, cfg, mix, tree)
     del tree
     jax.block_until_ready(eng.params)
     t_w = time.perf_counter()
@@ -420,7 +429,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     # stands in the program's place, and ``correct`` is its verdict.
     t_ref = time.perf_counter()
     picked = sample(readings, seed)
-    gaps, ctrl = logit_gaps(cfg, seed, picked, control)
+    gaps, ctrl = logit_gaps(cell, seed, picked, control)
     compared = ctrl if control else gaps
     mean = mean_gap(compared)
     limit = cell.check["logit_gap_mean_limit"]

@@ -1,9 +1,10 @@
 """The system under test, built from a configuration file.
 
-This is the one module of the benchmark that imports the program: the
-model code, the pipelined engine and the asyncio streaming server.  The
-weights come from ``bench.weights`` and are handed over in the program's
-own parameter tree.
+This and the architectures' program halves (``bench/arch/<name>_program.py``)
+are the modules of the benchmark that import the program: the model code,
+the pipelined engine and the asyncio streaming server.  The weights come
+from ``bench.weights`` and are handed over in the program's own parameter
+tree, which the architecture's program half lays out.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from bench import cells as C
 from bench import weights as W
 from repro.configs.base import ArchConfig, RunConfig
 from repro.core.policies import SoftmaxPolicy
@@ -23,34 +25,17 @@ from repro.runtime.paged_cache import PagedCacheConfig
 from repro.runtime.server import AsyncServingServer
 
 
-def arch(cfg: dict) -> ArchConfig:
-    d = W.model_dims(cfg)
-    return ArchConfig(
-        name=cfg["name"], family="dense", n_layers=d["layers"],
-        d_model=d["hidden"], n_heads=d["heads"], n_kv_heads=d["kv_heads"],
-        d_ff=d["ffn"], vocab_size=d["vocab"], head_dim=d["head_dim"],
-        qk_norm=d["qk_norm"], rope_theta=d["rope_theta"],
-        norm_eps=d["norm_eps"])
+def arch_config(arch, cfg: dict) -> ArchConfig:
+    return C.arch_program(arch).arch_config(cfg, arch.dims(cfg))
 
 
-def params(cfg: dict, seed: int) -> dict:
+def params(arch, cfg: dict, seed: int) -> dict:
     """The seeded weights (one jitted call) in the program's tree, checked
     against the shapes of the program's own initializer."""
-    w = W.make_all(cfg, seed)
-    top, lay = w["top"], w["layer"]
-    mixer = {k: lay[k] for k in ("wq", "wk", "wv", "wo")}
-    if "q_norm" in lay:
-        mixer["q_norm"] = {"scale": lay["q_norm"]}
-        mixer["k_norm"] = {"scale": lay["k_norm"]}
-    block = {"norm1": {"scale": lay["attn_norm"]}, "mixer": mixer,
-             "norm2": {"scale": lay["mlp_norm"]},
-             "ffn": {k: lay[k] for k in ("w_up", "w_down", "w_gate")}}
-    tree = {"embed": {"table": top["embed"]}, "periods": [block],
-            "final_norm": {"scale": top["final_norm"]},
-            "head": {"w": top["head"]}}
+    tree = C.arch_program(arch).tree(W.make_all(arch, cfg, seed))
     if cfg["serving"]["weights"] == "float32":
         tree = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
-    model = build_model(arch(cfg))
+    model = build_model(arch_config(arch, cfg))
     want = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     got = jax.tree_util.tree_map(lambda a: a.shape, tree)
     if jax.tree_util.tree_map(lambda a: a.shape, want) != got:
@@ -71,7 +56,7 @@ def run_config(cfg: dict) -> RunConfig:
     return RunConfig(dtype=s["weights"], softmax_policy=policy, kv_dtype=kv)
 
 
-def engine(cfg: dict, mix: dict, tree: dict) -> PipelinedEngine:
+def engine(arch, cfg: dict, mix: dict, tree: dict) -> PipelinedEngine:
     s = cfg["serving"]
     ps = PagedCacheConfig().page_size
     cache = PagedCacheConfig(n_pages=s["pool_pages"], page_size=ps,
@@ -80,8 +65,8 @@ def engine(cfg: dict, mix: dict, tree: dict) -> PipelinedEngine:
     econf = EngineConfig(n_slots=mix["n_slots"], cache=cache,
                          prefill_chunk=s["prefill_chunk"],
                          prefix_cache=s["prefix_cache"])
-    return PipelinedEngine(build_model(arch(cfg)), tree, run_config(cfg),
-                           econf)
+    return PipelinedEngine(build_model(arch_config(arch, cfg)), tree,
+                           run_config(cfg), econf)
 
 
 def server(eng: PipelinedEngine) -> AsyncServingServer:

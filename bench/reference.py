@@ -5,7 +5,8 @@ program: the weights are made again from the seed (``bench.weights``), the
 softmax follows the paper's Algorithm 1 (REXP) from its definition, and
 everything runs as plain ``jax.numpy`` at ``highest`` matmul precision,
 one layer at a time over a whole sequence, so that it fits beside nothing
-else on the chip.
+else on the chip.  Each kind of layer is its architecture module's
+``reference_layer`` (``bench/arch/<name>.py``), made of the helpers here.
 
 ``forward`` returns the logits at chosen positions of token sequences.
 With ``control=True`` every matmul takes its operands rounded to fp8
@@ -92,52 +93,6 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "softmax", "control"))
-def _layer(x, w, dims, softmax, control):
-    """One decoder layer over a whole (T, D) sequence, causal."""
-    d = dict(dims)
-    T = x.shape[0]
-    H, KV, Dh, eps = d["heads"], d["kv_heads"], d["head_dim"], d["norm_eps"]
-    G = H // KV
-    pos = jnp.arange(T)
-    h = _rms(x, w["attn_norm"], eps)
-    q = _mm(h, w["wq"], control).reshape(T, H, Dh).transpose(1, 0, 2)
-    k = _mm(h, w["wk"], control).reshape(T, KV, Dh).transpose(1, 0, 2)
-    v = _mm(h, w["wv"], control).reshape(T, KV, Dh).transpose(1, 0, 2)
-    if d["qk_norm"]:
-        q = _rms(q, w["q_norm"], eps)
-        k = _rms(k, w["k_norm"], eps)
-    q = _rope(q, pos, d["rope_theta"]) * Dh ** -0.5
-    k = _rope(k, pos, d["rope_theta"])
-    qg = q.reshape(KV, G, T, Dh)
-
-    def block(i):
-        rows = jax.lax.dynamic_slice_in_dim(qg, i * Q_BLOCK, Q_BLOCK, 2)
-        s = jnp.einsum("ngqd,nkd->ngqk", rows, k, precision="highest") \
-            if not control else jnp.einsum(
-                "ngqd,nkd->ngqk", _fp8(rows), _fp8(k), precision="highest")
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(pos[None, :] <= qpos[:, None], s, -jnp.inf)
-        if softmax is None:
-            p = softmax_exact(s)
-        else:
-            lut_e, lut_a, qmax = softmax
-            p = softmax_rexp(s, lut_e, lut_a, qmax)
-        if control:
-            return jnp.einsum("ngqk,nkd->ngqd", _fp8(p), _fp8(v),
-                              precision="highest")
-        return jnp.einsum("ngqk,nkd->ngqd", p, v, precision="highest")
-
-    out = jax.lax.map(block, jnp.arange(T // Q_BLOCK))   # (nb, KV, G, Qb, Dh)
-    out = out.transpose(1, 2, 0, 3, 4).reshape(H, T, Dh)
-    out = out.transpose(1, 0, 2).reshape(T, H * Dh)
-    x = x + _mm(out, w["wo"], control)
-    h = _rms(x, w["mlp_norm"], eps)
-    g = _mm(h, w["w_gate"], control)
-    u = _mm(h, w["w_up"], control)
-    return x + _mm(jax.nn.silu(g) * u, w["w_down"], control)
-
-
 @functools.partial(jax.jit, static_argnames=("eps", "control"))
 def _head(x, rows, norm, head, eps, control):
     return _mm(_rms(x[rows], norm, eps), head, control)
@@ -164,14 +119,15 @@ def softmax_of(cfg: dict):
             (1 << w_bits) - 1)
 
 
-def forward(cfg: dict, seed: int, seqs: list[np.ndarray],
+def forward(arch, cfg: dict, seed: int, seqs: list[np.ndarray],
             rows: list[np.ndarray], control: bool = False) -> list[np.ndarray]:
     """f32 logits ``(len(rows[i]), vocab)`` at positions ``rows[i]`` of each
     token sequence ``seqs[i]``.  Weights are made from ``seed`` one layer
-    at a time, and each layer is applied to every sequence before the
-    next is made."""
-    dims = W.model_dims(cfg)
-    top = W.make_top(cfg, seed)
+    at a time, and each layer is applied, by the architecture module
+    ``arch``'s ``reference_layer`` for its kind, to every sequence before
+    the next is made."""
+    dims = arch.dims(cfg)
+    top = W.make_top(arch, cfg, seed)
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
     xs = []
     for tokens in seqs:
@@ -181,9 +137,11 @@ def forward(cfg: dict, seed: int, seqs: list[np.ndarray],
     dkey = tuple(sorted(dims.items()))
     sm = softmax_of(cfg)
     with jax.default_matmul_precision("highest"):
-        for layer in range(dims["layers"]):
-            w = jax.tree_util.tree_map(f32, W.make_layer(cfg, seed, layer))
-            xs = [_layer(x, w, dkey, sm, control) for x in xs]
+        for layer, kind in enumerate(arch.layer_kinds(cfg)):
+            w = jax.tree_util.tree_map(
+                f32, W.make_layer(arch, cfg, seed, layer))
+            xs = [arch.reference_layer(kind, x, w, dkey, sm, control)
+                  for x in xs]
         norm, head = f32(top["final_norm"]), f32(top["head"])
         return [np.asarray(_head(x, jnp.asarray(r, jnp.int32), norm, head,
                                  dims["norm_eps"], control))

@@ -1,5 +1,6 @@
 """The roofline yardstick against counts made by hand."""
 
+from bench import cells as C
 from bench import work
 
 DIMS = {"heads": 8, "kv_heads": 1, "head_dim": 128, "hidden": 5120,
@@ -29,7 +30,16 @@ def test_prefill_from_zero_is_causal_triangle():
 
 def test_matmul_flops_per_token():
     per_layer = 5120 * (8 + 2) * 128 + 8 * 128 * 5120 + 3 * 5120 * 3200
-    assert work.matmul_flops_per_token(DIMS) == 2 * per_layer * 64
+    dense = C.arch("dense")
+    assert dense.matmul_flops_per_token(DIMS) == 2 * per_layer * 64
+
+
+def test_dense_attention_is_every_layer_alike():
+    dense = C.arch("dense")
+    assert dense.decode_attention(100, DIMS) == tuple(
+        64 * x for x in work.decode_row(100, DIMS))
+    assert dense.prefill_attention(10, 3, DIMS) == tuple(
+        64 * x for x in work.prefill_chunk(10, 3, DIMS))
 
 
 def test_roofline_takes_the_larger_bound():

@@ -5,6 +5,9 @@ They count what attention over the held heads needs, whatever kernel
 computes it: no extra pass over the keys, no padding columns of a block
 table, no upcast.  Each key of a row is read once (K and V), each query
 row read once and each output row written once, in the model's dtype.
+The counts here are one layer's; a configuration's architecture module
+(``bench/arch/<name>.py``) sums them over its layers, and counts its own
+weight-matmul and LM-head FLOPs.
 """
 
 from __future__ import annotations
@@ -43,18 +46,6 @@ def prefill_chunk(q_start: int, n: int, dims: dict, kv_bytes: int = 2,
     return flops, nbytes
 
 
-def matmul_flops_per_token(dims: dict) -> float:
-    """Weight-matmul FLOPs of one token through every layer."""
-    D, H, KV, Dh, F = (dims["hidden"], dims["heads"], dims["kv_heads"],
-                       dims["head_dim"], dims["ffn"])
-    per_layer = D * (H + 2 * KV) * Dh + H * Dh * D + 3 * D * F
-    return 2.0 * per_layer * dims["layers"]
-
-
-def head_flops(dims: dict) -> float:
-    return 2.0 * dims["hidden"] * dims["vocab"]
-
-
 def roofline_s(flops: float, nbytes: float, peak: dict) -> float:
     """Least time for the work: the larger of its compute and memory bounds."""
     return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
@@ -67,10 +58,9 @@ def window_decode(r) -> tuple[float, float, int]:
     n = 0
     for t, kv in r.decode_rows:
         if r.in_window(t):
-            df, db = decode_row(kv, r.dims)
+            df, db = r.arch.decode_attention(kv, r.dims)
             f, b, n = f + df, b + db, n + 1
-    L = r.dims["layers"]
-    return f * L, b * L, n
+    return f, b, n
 
 
 def window_prefill(r) -> tuple[float, float, int, int]:
@@ -80,17 +70,17 @@ def window_prefill(r) -> tuple[float, float, int, int]:
     n = toks = 0
     for t, start, m in r.chunks:
         if r.in_window(t):
-            df, db = prefill_chunk(start, m, r.dims)
+            df, db = r.arch.prefill_attention(start, m, r.dims)
             f, b, n, toks = f + df, b + db, n + 1, toks + m
-    L = r.dims["layers"]
-    return f * L, b * L, n, toks
+    return f, b, n, toks
 
 
 def model_flops(r, phase: str) -> float:
     """Model FLOPs of the window's work: ``decode`` (one token per slot
     row, with its LM head), ``prefill`` (every prompt token, one LM head
     row per chunk) or ``all``."""
-    per_tok, head = matmul_flops_per_token(r.dims), head_flops(r.dims)
+    per_tok = r.arch.matmul_flops_per_token(r.dims)
+    head = r.arch.head_flops(r.dims)
     total = 0.0
     if phase in ("decode", "all"):
         f, _, rows = window_decode(r)
